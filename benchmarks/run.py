@@ -51,6 +51,9 @@ def main() -> None:
                     help="CI smoke: REPRO_BENCH_FAST=1 and only the "
                          "suites check_regression.py gates on")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.quick:
         os.environ["REPRO_BENCH_FAST"] = "1"
         if args.only == "all":

@@ -48,12 +48,13 @@ def pack_columns(w: jnp.ndarray, *, group: int = 1
     return jnp.asarray(wf[kept]), jnp.asarray(kept)
 
 
-def _kernel(*refs, n_k: int, f32_dot: bool = False, has_bias: bool = False,
+def _kernel(*refs, n_k: int, interpret: bool, has_bias: bool = False,
             activation=None):
     """Accumulate one (bm × bp) fp32 output tile over K chunks.
 
-    ``f32_dot``: interpret-mode only (CPU DotThunk lacks BF16×BF16→F32);
-    on TPU the MXU handles bf16 inputs with f32 accumulation natively.
+    In interpret mode a bf16 tile is upcast (CPU DotThunk lacks
+    BF16×BF16→F32); on TPU the MXU handles bf16 inputs with f32
+    accumulation natively.
     The optional (bias, activation) epilogue runs on the finished fp32
     accumulator at the LAST K step — the grid is sequential with k fastest,
     so the tile is complete exactly then.
@@ -69,7 +70,11 @@ def _kernel(*refs, n_k: int, f32_dot: bool = False, has_bias: bool = False,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x, w = x_ref[...], w_ref[...]
-    if f32_dot:
+    if interpret:
+        # contract a K-major x tile, as XLA:CPU lays out the fused gather
+        # of the XLA plans: the same layout sums in the same order
+        x = jax.lax.optimization_barrier(x.T).T
+    if interpret and x.dtype == jnp.bfloat16:
         x, w = x.astype(jnp.float32), w.astype(jnp.float32)
     o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
@@ -95,7 +100,7 @@ def column_gemm(
     block_m: int = 128,
     block_p: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     activation: Optional[str] = None,        # relu | silu | gelu | None
     grid_order: str = "mp",                  # outer-loop order; k innermost
 ) -> jnp.ndarray:
@@ -109,6 +114,10 @@ def column_gemm(
     fastest so the fp32 output tile is revisited on consecutive grid steps
     (the accumulate-in-place contract of the kernel).
     """
+    from repro.kernels.ops import _default_interpret
+
+    if interpret is None:
+        interpret = _default_interpret()
     check_activation(activation)
     M, Q = x.shape
     K, P = w_packed.shape
@@ -123,7 +132,6 @@ def column_gemm(
     if M % block_m or P % block_p:
         raise ValueError(f"(M={M}, P={P}) not tiled by ({block_m}, {block_p})")
 
-    needs_f32 = interpret and xg.dtype == jnp.bfloat16
     grid, im_x, im_w, im_b, im_o = accum_gemm_grid(
         grid_order, M // block_m, P // block_p, n_k)
     in_specs = [
@@ -135,12 +143,13 @@ def column_gemm(
         in_specs.append(pl.BlockSpec((1, block_p), im_b))
         operands.append(bias.reshape(1, P))
     out = pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k, f32_dot=needs_f32,
+        functools.partial(_kernel, n_k=n_k, interpret=interpret,
                           has_bias=bias is not None, activation=activation),
         out_shape=jax.ShapeDtypeStruct((M, P), jnp.float32),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_p), im_o),
         interpret=interpret,
+        name="column_gemm",
     )(*operands)
     return out.astype(x.dtype)

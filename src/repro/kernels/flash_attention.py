@@ -50,14 +50,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, seq_len: int, block_q: int,
 
     def body(kj, carry):
         m, l, acc = carry
-        # index the leading dim with dslice(0, 1): a bare int here breaks the
-        # interpret-mode load discharge when the fori_loop bounds are dynamic
-        k = pl.load(k_ref, (pl.dslice(0, 1),
-                            pl.dslice(kj * block_k, block_k),
-                            slice(None)))[0]        # (block_k, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1),
-                            pl.dslice(kj * block_k, block_k),
-                            slice(None)))[0]
+        start = pl.multiple_of(kj * block_k, block_k)
+        k = k_ref[0, pl.ds(start, block_k), :]      # (block_k, hd)
+        v = v_ref[0, pl.ds(start, block_k), :]
         if f32_dot:
             k = k.astype(jnp.float32)
             v = v.astype(jnp.float32)
@@ -117,9 +112,13 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     softmax_scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Flash attention forward. GQA: q heads grouped onto KV heads."""
+    from repro.kernels.ops import _default_interpret
+
+    if interpret is None:
+        interpret = _default_interpret()
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -151,5 +150,6 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this box is CPU-only; interpret mode
-executes the kernel body in Python for correctness validation) and False on
-real TPU backends.
+``interpret`` defaults to True off-TPU (interpret mode runs the kernel body
+as ordinary JAX ops, for correctness checks on CPU) and False on a TPU.
+``_default_interpret`` is the one place that decides it: every kernel entry
+point, the packed dispatch and the tuner call it when ``interpret`` is None.
 
 NOTE: the hand-driven pack functions here are DEPRECATED for model-facing
 use — ``repro.sparse`` owns packing now (``PrunedArtifact.pack()`` resolves
@@ -17,11 +18,8 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import column_gemm as _cg
 from repro.kernels import flash_attention as _fa
@@ -56,10 +54,8 @@ def pack_tile_pattern(w, **kw):
     return _pg.pack_tile_pattern(w, **kw)
 
 
-def tile_pattern_matmul(x, w_packed, lane_idx, *, interpret=None, **kw):
-    if interpret is None:
-        interpret = _default_interpret()
-    return _pg.pattern_gemm(x, w_packed, lane_idx, interpret=interpret, **kw)
+def tile_pattern_matmul(x, w_packed, lane_idx, **kw):
+    return _pg.pattern_gemm(x, w_packed, lane_idx, **kw)
 
 
 # -- column-pruned GEMM -------------------------------------------------------
@@ -69,18 +65,14 @@ def pack_columns(w, **kw):
     return _cg.pack_columns(w, **kw)
 
 
-def column_matmul(x, w_packed, kept_idx, *, interpret=None, **kw):
-    if interpret is None:
-        interpret = _default_interpret()
-    return _cg.column_gemm(x, w_packed, kept_idx, interpret=interpret, **kw)
+def column_matmul(x, w_packed, kept_idx, **kw):
+    return _cg.column_gemm(x, w_packed, kept_idx, **kw)
 
 
 # -- flash attention ----------------------------------------------------------
 
-def flash_attention(q, k, v, *, interpret=None, **kw):
-    if interpret is None:
-        interpret = _default_interpret()
-    return _fa.flash_attention(q, k, v, interpret=interpret, **kw)
+def flash_attention(q, k, v, **kw):
+    return _fa.flash_attention(q, k, v, **kw)
 
 
 # -- pattern conv ---------------------------------------------------------------
@@ -97,7 +89,5 @@ def pack_pattern_conv(w4, pat_ids, patterns=None):
 
 def pattern_conv(x, w_packed, taps, bias=None, *, interpret=None,
                  activation=None):
-    if interpret is None:
-        interpret = _default_interpret()
     return _pc.pattern_conv(x, w_packed, taps, bias, interpret=interpret,
                             activation=activation)

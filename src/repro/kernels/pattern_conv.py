@@ -133,7 +133,7 @@ def pattern_conv_gemm(
     block_m: int = 256,
     block_a: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     activation: Optional[str] = None,       # relu | silu | gelu | None
     grid_order: str = "mp",                 # outer-loop order; k innermost
 ) -> jnp.ndarray:
@@ -145,6 +145,10 @@ def pattern_conv_gemm(
     whether row tiles (``mp``) or filter tiles (``pm``) run outermost —
     k always iterates fastest for the accumulate-in-place output tile.
     """
+    from repro.kernels.ops import _default_interpret
+
+    if interpret is None:
+        interpret = _default_interpret()
     check_activation(activation)
     M, K = xg.shape
     K2, A = w_packed.shape
@@ -180,6 +184,7 @@ def pattern_conv_gemm(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, ba), im_o),
         interpret=interpret,
+        name="pattern_conv_gemm",
     )(*operands)
     return out[:M, :A].astype(xg.dtype)
 
@@ -190,7 +195,7 @@ def pattern_conv(
     taps: np.ndarray,            # (C, keep)
     bias: Optional[jnp.ndarray] = None,     # (A,) fused-epilogue bias
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     activation: Optional[str] = None,
 ) -> jnp.ndarray:
     """Pattern-pruned 3×3 conv, stride 1, SAME padding → (B, H, W, A).

@@ -33,6 +33,7 @@ from repro.core import (
     sparsity,
 )
 from repro.models import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -109,6 +110,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)
 

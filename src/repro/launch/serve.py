@@ -34,6 +34,7 @@ import jax
 from repro.checkpoint import restore_pytree
 from repro.configs import get_config, reduced_config
 from repro.models import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 
 log = logging.getLogger(__name__)
@@ -73,6 +74,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only; no decode serving")

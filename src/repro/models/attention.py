@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG_INF = -1e30
+_SUBLANES = 8            # rows of one TPU vector-register tile
 
 
 def flash_prefill_supported(seq_len: int, num_heads: int, num_kv_heads: int,
@@ -43,14 +44,17 @@ def flash_prefill_supported(seq_len: int, num_heads: int, num_kv_heads: int,
 
     The Pallas kernel tiles S by min(block, S) and groups q heads onto kv
     heads, so it needs S divisible by both (auto-true for S ≤ block) and an
-    exact GQA ratio. Callers that get ``False`` keep the XLA blockwise
-    path — the serve-path fallback contract (``LM.prefill``).
+    exact GQA ratio. Each block must also span whole 8-row sublane tiles:
+    the TPU compiler refuses a bf16 kernel whose kv slices start off that
+    tiling (a ragged S=37 prompt). Callers that get ``False`` keep the XLA
+    blockwise path — the serve-path contract (``LM.prefill``).
     """
     if seq_len <= 0 or num_kv_heads <= 0:
         return False
     bq = min(block_q, seq_len)
     bk = min(block_k, seq_len)
     return (seq_len % bq == 0 and seq_len % bk == 0
+            and bq % _SUBLANES == 0 and bk % _SUBLANES == 0
             and num_heads % num_kv_heads == 0)
 
 
@@ -429,14 +433,20 @@ def slot_prompt_rows(capacity: int, prompt_len: int, ring: bool):
     masked by ``decode_attention``) everywhere else. Resetting a slot's
     row to this is what invalidates a retired occupant's stale KV when a
     batch slot is reused mid-decode: the bytes stay, the mask hides them.
+
+    The geometry is static, so it is built in NumPy and enters the program
+    as constants: traced, the row is a scatter whose indices and updates
+    come from one iota, and the TPU compiler (libtpu 0.0.34) aborts on
+    that scatter.
     """
     S, C = prompt_len, capacity
     if not ring and S > C:
         raise ValueError(f"prompt_len={S} exceeds cache capacity={C}")
     keep = min(C, S)
-    pos = jnp.arange(S - keep, S, dtype=jnp.int32)
+    pos = np.arange(S - keep, S, dtype=np.int32)
     rows = pos % C if ring else pos
-    slot_pos_row = jnp.full((C,), -1, jnp.int32).at[rows].set(pos)
+    slot_pos_row = np.full((C,), -1, np.int32)
+    slot_pos_row[rows] = pos
     return rows, keep, slot_pos_row
 
 

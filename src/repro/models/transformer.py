@@ -56,9 +56,17 @@ from repro.models.layers import (
 )
 from repro.models.moe import moe_apply, moe_init
 from repro.parallel.sharding import constrain
+from repro.runtime.telemetry import get_registry
 
 MOE_AUX_COEF = 0.01
 LOSS_CHUNK = 512
+
+
+def _kernels_compiled() -> bool:
+    """True where Pallas kernels compile for the device (not interpret)."""
+    from repro.kernels.ops import _default_interpret
+
+    return not _default_interpret()
 
 
 @dataclasses.dataclass
@@ -325,7 +333,13 @@ class LM:
         # tile (ragged S, inexact GQA ratio after TP head expansion) fall
         # back to XLA blockwise per call, so serving never crashes on an
         # unsupported prompt length.
-        if use_flash and flash_prefill_supported(S, qe.shape[2], ke.shape[2]):
+        flash_ok = flash_prefill_supported(S, qe.shape[2], ke.shape[2])
+        if use_flash and not flash_ok:
+            # trace-time count: one per compiled prefill shape that asked
+            # for flash and was given blockwise attention instead
+            get_registry().counter("attention.flash_declined_total",
+                                   seq_len=S).inc()
+        if use_flash and flash_ok:
             from repro.kernels import ops as kops
 
             out = kops.flash_attention(
@@ -589,11 +603,10 @@ class LM:
             logits = self.lm_logits(params, h)
             return cache, logits
 
-        # serving path: the Pallas flash kernel engages on real TPU
-        # backends by default (interpret-mode flash is a correctness tool,
-        # not a fast path)
-        use_flash = (jax.default_backend() == "tpu") if flash is None \
-            else bool(flash)
+        # serving path: the Pallas flash kernel engages wherever kernels
+        # run compiled (interpret-mode flash is a correctness tool, not a
+        # fast path)
+        use_flash = _kernels_compiled() if flash is None else bool(flash)
         h, _, kv = self.hidden_states(params, inputs, collect_kv=True,
                                       use_flash=use_flash)
         cache = self.init_cache(B, seq_len)
@@ -647,8 +660,7 @@ class LM:
                 "recurrent-state slot admission is not implemented"
             )
         S = prompt.shape[1]
-        use_flash = (jax.default_backend() == "tpu") if flash is None \
-            else bool(flash)
+        use_flash = _kernels_compiled() if flash is None else bool(flash)
         h, _, kv = self.hidden_states(params, prompt, collect_kv=True,
                                       use_flash=use_flash)
         if cfg.family == "hybrid":

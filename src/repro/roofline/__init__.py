@@ -17,10 +17,12 @@ from repro.roofline.hlo_costs import (
     while_parts,
 )
 from repro.roofline.hw import (
+    CHIP_PEAKS,
     HBM_BW,
     ICI_BW,
     PEAK_FLOPS_BF16,
     RooflineTerms,
+    chip_peaks,
     model_flops_infer,
     model_flops_train,
     roofline_terms,

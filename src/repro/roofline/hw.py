@@ -1,4 +1,4 @@
-"""TPU v5e hardware constants (the assignment's target chip) + roofline terms.
+"""Per-chip peak table keyed by ``device_kind`` + roofline terms.
 
     compute term    = FLOPs / (chips × peak FLOP/s)
     memory term     = bytes / (chips × HBM bw)
@@ -15,9 +15,44 @@ import dataclasses
 from typing import Dict
 
 
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (~per chip per direction)
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator generation."""
+
+    bf16_flops: float        # FLOP/s
+    hbm_bw: float            # bytes/s
+    ici_bw: float            # bytes/s per inter-chip link, one direction
+    source: str
+
+
+# Keyed by ``jax.devices()[0].device_kind``. A device missing here has no
+# peaks: ``chip_peaks`` raises rather than assume another chip's.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect per chip over 4 links
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+# the chip the static cost models (hlo_costs, report, attribution) target
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; raises ``KeyError`` for a chip not listed."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                       ) from None
+
+
+_TARGET = chip_peaks(TARGET_DEVICE_KIND)
+PEAK_FLOPS_BF16 = _TARGET.bf16_flops
+HBM_BW = _TARGET.hbm_bw
+ICI_BW = _TARGET.ici_bw
 
 
 @dataclasses.dataclass(frozen=True)

@@ -117,6 +117,15 @@ def _row_block(n: int, cap: int = 128) -> int:
     return n if n <= cap else cap
 
 
+def _dot_operands(interpret: bool, *ops: jnp.ndarray):
+    """Interpret mode runs on the CPU backend, whose dot lacks
+    BF16×BF16→F32: upcast there (exact — a bf16 product fits in f32).
+    On a TPU the MXU takes bf16 with f32 accumulation as is."""
+    if interpret and any(o.dtype == jnp.bfloat16 for o in ops):
+        return tuple(o.astype(jnp.float32) for o in ops)
+    return ops
+
+
 def _pad_rows(x: jnp.ndarray, block: int):
     pad = (-x.shape[0]) % block
     if pad:
@@ -303,8 +312,8 @@ def _dense_pack(w: jnp.ndarray, spec: Any) -> Optional[PackedTensor]:
 def _dense_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
     # one implementation only: nothing to tune (exec_plan ignored)
     def fn(x, pt, bias):
-        y = jnp.dot(x, pt.buf("w_packed"),
-                    preferred_element_type=jnp.float32)
+        a, w = _dot_operands(interpret, x, pt.buf("w_packed"))
+        y = jnp.dot(a, w, preferred_element_type=jnp.float32)
         return apply_epilogue(y, bias, activation).astype(x.dtype)
 
     return fn
@@ -445,8 +454,8 @@ def _tile_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
         if impl == "gather_e" and (not ng or ng * keep != Kp):
             impl = "gather"               # defensive: odd geometry
 
-        def fn(x, pt, bias):
-            wpb = _tile_wpb(pt)
+        def fn(x_in, pt, bias):
+            x, wpb = _dot_operands(interpret, x_in, _tile_wpb(pt))
             li = pt.buf("lane_idx")
             if impl == "gather":
                 if nb == 1:
@@ -495,7 +504,7 @@ def _tile_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
                     g, wpb, (((1,), (1,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32)       # (nb, M, bp)
                 y = jnp.transpose(y, (1, 0, 2)).reshape(M, P)
-            return apply_epilogue(y, bias, activation).astype(x.dtype)
+            return apply_epilogue(y, bias, activation).astype(x_in.dtype)
 
         return fn
 
@@ -625,17 +634,17 @@ def _column_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
         # rows beat strided columns) and contracts the leading axis.
         impl = exec_plan.impl
 
-        def fn(x, pt, bias):
+        def fn(x_in, pt, bias):
+            x, w = _dot_operands(interpret, x_in, pt.buf("w_packed"))
             if impl == "gather":
                 xg = jnp.take(x, pt.buf("kept_idx"), axis=1)
-                y = jnp.dot(xg, pt.buf("w_packed"),
-                            preferred_element_type=jnp.float32)
+                y = jnp.dot(xg, w, preferred_element_type=jnp.float32)
             else:
                 g = jnp.take(x.T, pt.buf("kept_idx"), axis=0)   # (K, M)
                 y = jax.lax.dot_general(
-                    g, pt.buf("w_packed"), (((0,), (0,)), ((), ())),
+                    g, w, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            return apply_epilogue(y, bias, activation).astype(x.dtype)
+            return apply_epilogue(y, bias, activation).astype(x_in.dtype)
 
         return fn
 
@@ -733,7 +742,8 @@ def conv_gemm_runner(pt, plan, *, interpret: bool,
     """
     if plan.impl == "xla":
         def fn(xg, w, bias=None):
-            y = jnp.dot(xg, w, preferred_element_type=jnp.float32)
+            a, b = _dot_operands(interpret, xg, w)
+            y = jnp.dot(a, b, preferred_element_type=jnp.float32)
             return apply_epilogue(y, bias, activation).astype(xg.dtype)
 
         return fn

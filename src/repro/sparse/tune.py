@@ -35,6 +35,7 @@ served tokens, only their latency. ``tests/test_tune.py`` enforces this.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -51,6 +52,13 @@ _MATMUL_SCHEMES = ("tile_pattern", "column")
 _CONV_SCHEMES = ("pattern", "pattern_shared")
 
 _DEFAULT_SMALL_M = 32
+
+# gather_t emits one gather+dot per output panel: past this many panels
+# (an lm_head over a 150k vocabulary has ~1,200) compiling the unrolled
+# program costs more than any timing it could win
+_UNROLLED_PANELS_MAX = 128
+
+_log = logging.getLogger(__name__)
 
 
 # ---------------------------------------------------------------------------
@@ -259,9 +267,11 @@ def candidate_plans(pt: PackedTensor, kind: str, M: int,
     if interpret:
         return [Plan("gather")]
     if pt.scheme == "tile_pattern":
-        cands = [Plan("gather"), Plan("gather_t"), Plan("gather_e")]
+        cands = [Plan("gather"), Plan("gather_e")]
         nb = pt.buf("lane_idx").shape[-2] if pt.buf(
             "lane_idx").ndim >= 2 else 1
+        if nb <= _UNROLLED_PANELS_MAX:
+            cands.append(Plan("gather_t"))
         if nb > 1:
             cands.append(Plan("gather_tb"))
     else:
@@ -334,14 +344,29 @@ def _canonical_slice(pt: PackedTensor) -> PackedTensor:
                         tuple(b[idx] for b in pt.buffers), pt.meta)
 
 
+def _candidate_failed(pt: PackedTensor, kind: str, M: int, plan: Plan,
+                      err: Exception) -> float:
+    """Log and count a candidate that failed to build or run."""
+    from repro.runtime.telemetry import get_registry
+
+    get_registry().counter("tune.candidate_failures_total", kind=kind,
+                           scheme=pt.scheme, plan=plan.to_str()).inc()
+    first = str(err).strip().splitlines()[0] if str(err).strip() else ""
+    _log.warning("tune: %s %s candidate %s failed at M=%d: %s: %s", kind,
+                 pt.scheme, plan.to_str(), M, type(err).__name__, first)
+    return -1.0
+
+
 def tune_plan(pt: PackedTensor, kind: str, M: int, *,
               interpret: Optional[bool] = None, iters: int = 3,
               ) -> Tuple[Optional[Plan], Dict[str, float]]:
     """Time every candidate plan; return (winner, per-plan median ms).
 
     Timing uses a bias/activation-free GEMM as the proxy for all epilogue
-    variants of the bucket (the epilogue cost is plan-invariant). Candidates
-    that fail to build/run are skipped (recorded as -1 in the report).
+    variants of the bucket (the epilogue cost is plan-invariant). A
+    candidate that fails to build or run is logged, counted in
+    ``tune.candidate_failures_total{plan}`` and recorded as -1 in the
+    report; it never wins.
     """
     from repro.kernels.ops import _default_interpret
     from repro.sparse import registry as reg
@@ -364,8 +389,8 @@ def tune_plan(pt: PackedTensor, kind: str, M: int, *,
                 fn = jax.jit(reg.conv_gemm_runner(pt, c,
                                                   interpret=interpret))
                 jax.block_until_ready(fn(xg, w))           # builds + runs
-            except Exception:
-                report[c.to_str()] = -1.0
+            except Exception as e:
+                report[c.to_str()] = _candidate_failed(pt, kind, M, c, e)
                 continue
             fns[c.to_str()] = (lambda fn=fn: fn(xg, w))
     else:
@@ -378,8 +403,8 @@ def tune_plan(pt: PackedTensor, kind: str, M: int, *,
                 fn = jax.jit(handler.plan(pt, M, False, None, interpret,
                                           exec_plan=c))
                 jax.block_until_ready(fn(x, pt, None))
-            except Exception:
-                report[c.to_str()] = -1.0
+            except Exception as e:
+                report[c.to_str()] = _candidate_failed(pt, kind, M, c, e)
                 continue
             fns[c.to_str()] = (lambda fn=fn: fn(x, pt, None))
     from repro.runtime.telemetry import get_registry
@@ -443,6 +468,8 @@ def tune_packed_tree(tree: Any, ms: Iterable[int], *,
             if plan is None:
                 continue
             key = plan_meta_key(kind, bucket)
+            _log.info("tune: %s %s -> %s (candidate ms %s)", path, key,
+                      plan.to_str(), times)
             meta = [kv for kv in meta if kv[0] != key]
             meta.append((key, plan.to_str()))
             wrote = True

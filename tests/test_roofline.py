@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.roofline import analyze_hlo, roofline_terms
+from repro.roofline import analyze_hlo, chip_peaks, roofline_terms
 from repro.roofline.hw import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 
 
@@ -19,9 +19,7 @@ def _compile(f, *args):
 
 
 def _xla_costs(compiled):
-    """cost_analysis() returns a dict on newer jax, [dict] on older."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
+    return compiled.cost_analysis()
 
 
 class TestUnrolled:
@@ -121,6 +119,18 @@ class TestCollectives:
         c = jax.jit(lambda x: (x @ x).sum()).lower(x).compile()
         mine = analyze_hlo(c.as_text())
         assert mine.collective_total == 0.0
+
+
+class TestChipPeaks:
+    def test_v5e_published_peaks(self):
+        p = chip_peaks("TPU v5 lite")
+        assert (p.bf16_flops, p.hbm_bw) == (197e12, 819e9)
+        assert "TPU v5e" in p.source
+        assert PEAK_FLOPS_BF16 == p.bf16_flops and HBM_BW == p.hbm_bw
+
+    def test_unknown_device_raises(self):
+        with pytest.raises(KeyError, match="no published peaks"):
+            chip_peaks("cpu")
 
 
 class TestRooflineTerms:

@@ -17,13 +17,9 @@ from repro.parallel.sharding import (
 
 @pytest.fixture(scope="module")
 def mesh():
-    # single real device: a 1×1 mesh — rule LOGIC is device-count agnostic.
-    # jax.sharding.AxisType only exists on newer jax; Auto is the default
-    # axis type there, so omitting the kwarg is equivalent.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh((1, 1), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    return jax.make_mesh((1, 1), ("data", "model"))
+    # single real device: a 1×1 mesh — rule LOGIC is device-count agnostic
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _rules(mesh_shape=(16, 16)):

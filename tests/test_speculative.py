@@ -78,6 +78,16 @@ def _caches_match(a, b, *, exact_kv: bool):
     return True
 
 
+# Layer 0's k/v project the same embeddings in the chunked and the
+# sequential program, so they stay bit-equal. Deeper layers read attention
+# outputs, and XLA:CPU (jax 0.9) contracts the K-query einsums of
+# ``chunk_attention`` with its operands swapped relative to the one-query
+# einsums of ``decode_attention``: the two agree to float32 rounding, no
+# longer bit for bit (atol 1e-5, as on the ring path).
+def _layer0_kv_equal(a, b):
+    return all(jnp.array_equal(a[key][0], b[key][0]) for key in ("k", "v"))
+
+
 # ---------------------------------------------------------------------------
 # model-level primitives: verify_chunk + snapshot/rollback
 # ---------------------------------------------------------------------------
@@ -99,7 +109,8 @@ class TestVerifyChunk:
         c_ch, ch = model.verify_chunk(params, cache, toks)
         assert jnp.allclose(seq, ch, atol=1e-5)
         assert jnp.array_equal(jnp.argmax(seq, -1), jnp.argmax(ch, -1))
-        assert _caches_match(c_seq, c_ch, exact_kv=True)
+        assert _caches_match(c_seq, c_ch, exact_kv=False)
+        assert _layer0_kv_equal(c_seq, c_ch)
 
     def test_rollback_equals_partial_decode(self, lm):
         """Snapshot → verify K → rollback(keep) must leave a cache
@@ -130,7 +141,8 @@ class TestVerifyChunk:
                         c_nxt["slot_pos"][1]),
                     "pos": c_ref["pos"].at[1].set(c_nxt["pos"][1]),
                 }
-        assert _caches_match(c_ref, c_rb, exact_kv=True)
+        assert _caches_match(c_ref, c_rb, exact_kv=False)
+        assert _layer0_kv_equal(c_ref, c_rb)
 
     def test_rollback_across_ring_wrap(self, swa_lm):
         """Ring cache (SWA): verify across the wrap boundary overwrites

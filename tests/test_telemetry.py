@@ -192,6 +192,17 @@ def lm():
     return cfg, model, params
 
 
+def _running_sum(values):
+    """Left-to-right float sum, the way ``Histogram.observe`` accumulates.
+
+    Python 3.12's built-in ``sum`` compensates rounding (Neumaier), so it
+    can differ from a running ``+=`` in the last bit."""
+    total = 0.0
+    for v in values:
+        total += v
+    return total
+
+
 def _reqs(cfg, n=5):
     return [Request(uid=i, prompt=(jnp.arange(4 + 2 * i) + i) % cfg.vocab_size,
                     max_new_tokens=3 + i) for i in range(n)]
@@ -324,12 +335,14 @@ class TestEngineTelemetry:
         assert len(firsts) == len(reqs)
         h_ttft = reg.histogram("serve.ttft_seconds", **E)
         assert h_ttft.count == len(firsts)
-        assert sum(e["ts"] - e["arrival"] for e in firsts) == h_ttft.sum
+        assert _running_sum(e["ts"] - e["arrival"] for e in firsts) \
+            == h_ttft.sum
 
         admits = by["admit"]
         h_q = reg.histogram("serve.queue_wait_seconds", **E)
         assert h_q.count == len(admits)
-        assert sum(e["ts"] - e["arrival"] for e in admits) == h_q.sum
+        assert _running_sum(e["ts"] - e["arrival"] for e in admits) \
+            == h_q.sum
 
         t_first = {e["uid"]: e["ts"] for e in firsts}
         off_tpot = sum((e["ts"] - t_first[e["uid"]]) / (e["tokens"] - 1)

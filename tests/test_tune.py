@@ -260,6 +260,7 @@ class TestFlashPrefill:
         assert flash_prefill_supported(64, 4, 2)         # S <= block
         assert flash_prefill_supported(1024, 4, 2)       # S % 512 == 0
         assert not flash_prefill_supported(600, 4, 2)    # ragged S
+        assert not flash_prefill_supported(37, 4, 2)     # off the 8-row tile
         assert not flash_prefill_supported(64, 5, 2)     # inexact GQA
         assert not flash_prefill_supported(0, 4, 2)
 
@@ -277,6 +278,24 @@ class TestFlashPrefill:
         np.testing.assert_allclose(np.asarray(logits_flash),
                                    np.asarray(logits_block),
                                    rtol=2e-4, atol=2e-4)
+
+    def test_declined_shape_is_counted(self):
+        from repro.runtime.telemetry import get_registry
+
+        cfg = ModelConfig(name="tiny", family="dense", num_layers=1,
+                          d_model=64, num_heads=4, num_kv_heads=2,
+                          head_dim=16, d_ff=128, vocab_size=128,
+                          param_dtype="float32")
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        declined = get_registry().counter("attention.flash_declined_total",
+                                          seq_len=37)
+        before = declined.value
+        prompt = jnp.arange(37)[None] % cfg.vocab_size
+        _, logits = model.prefill(params, prompt, 40, flash=True)
+        assert declined.value == before + 1       # one trace, blockwise
+        _, ref = model.prefill(params, prompt, 40, flash=False)
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref))
 
 
 class TestGenerateBucketing:
