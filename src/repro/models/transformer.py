@@ -303,23 +303,25 @@ class LM:
             rope = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
         h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
         attn_p = bp["attn"]
-        # qkv bias rides the GEMM epilogue (fused in-kernel when packed)
-        q = dense_apply(h, attn_p["wq"],
-                        bias=attn_p["bq"] if cfg.qkv_bias else None)
-        k = dense_apply(h, attn_p["wk"],
-                        bias=attn_p["bk"] if cfg.qkv_bias else None)
-        v = dense_apply(h, attn_p["wv"],
-                        bias=attn_p["bv"] if cfg.qkv_bias else None)
         B, S, _ = x.shape
-        q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
         qa, ka = self._attn_axes()
-        q = constrain(q, qa)
-        k = constrain(k, ka)
-        v = constrain(v, ka)
-        q = apply_rope_tables(q, *rope)
-        k = apply_rope_tables(k, *rope)
+        with jax.named_scope("qkv"):
+            # qkv bias rides the GEMM epilogue (fused in-kernel when packed)
+            q = dense_apply(h, attn_p["wq"],
+                            bias=attn_p["bq"] if cfg.qkv_bias else None)
+            k = dense_apply(h, attn_p["wk"],
+                            bias=attn_p["bk"] if cfg.qkv_bias else None)
+            v = dense_apply(h, attn_p["wv"],
+                            bias=attn_p["bv"] if cfg.qkv_bias else None)
+            q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+            q = constrain(q, qa)
+            k = constrain(k, ka)
+            v = constrain(v, ka)
+        with jax.named_scope("rope"):
+            q = apply_rope_tables(q, *rope)
+            k = apply_rope_tables(k, *rope)
         kv = (k, v) if collect_kv else None    # cache keeps original KV heads
 
         # §Perf iteration 2: head-parallel attention for any (H, KV, TP)
@@ -339,19 +341,23 @@ class LM:
             # for flash and was given blockwise attention instead
             get_registry().counter("attention.flash_declined_total",
                                    seq_len=S).inc()
-        if use_flash and flash_ok:
-            from repro.kernels import ops as kops
+        with jax.named_scope("attention"):
+            if use_flash and flash_ok:
+                from repro.kernels import ops as kops
 
-            out = kops.flash_attention(
-                qe, ke, ve, causal=cfg.causal, window=cfg.sliding_window,
-                block_q=min(512, S), block_k=min(512, S),
-            )[:, :, :H, :]
-        else:
-            out = blockwise_attention(
-                qe, ke, ve, causal=cfg.causal, window=cfg.sliding_window,
-                chunk=min(512, S),
-            )[:, :, :H, :]
-        out = dense_apply(out.reshape(B, S, cfg.attn_dim), bp["attn"]["wo"])
+                out = kops.flash_attention(
+                    qe, ke, ve, causal=cfg.causal,
+                    window=cfg.sliding_window,
+                    block_q=min(512, S), block_k=min(512, S),
+                )[:, :, :H, :]
+            else:
+                out = blockwise_attention(
+                    qe, ke, ve, causal=cfg.causal,
+                    window=cfg.sliding_window, chunk=min(512, S),
+                )[:, :, :H, :]
+        with jax.named_scope("o_proj"):
+            out = dense_apply(out.reshape(B, S, cfg.attn_dim),
+                              bp["attn"]["wo"])
         return out, kv
 
     def _mixer_and_mlp(self, bp, x, positions, *, collect_kv: bool = False,
@@ -381,16 +387,17 @@ class LM:
         x = x + mixer
         x = constrain(x, self._res_axes())
 
-        h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-        if cfg.num_experts:
-            y, aux = moe_apply(
-                bp["moe"], h, top_k=cfg.moe_top_k,
-                capacity_factor=cfg.capacity_factor,
-            )
-        elif cfg.d_ff:
-            y = ffn_apply(bp["mlp"], h, cfg.ffn_type)
-        else:
-            y = jnp.zeros_like(x)
+        with jax.named_scope("mlp"):
+            h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
+            if cfg.num_experts:
+                y, aux = moe_apply(
+                    bp["moe"], h, top_k=cfg.moe_top_k,
+                    capacity_factor=cfg.capacity_factor,
+                )
+            elif cfg.d_ff:
+                y = ffn_apply(bp["mlp"], h, cfg.ffn_type)
+            else:
+                y = jnp.zeros_like(x)
         x = x + y
         return constrain(x, self._res_axes()), aux, kv
 
@@ -439,10 +446,11 @@ class LM:
             # tables (ServeEngine bake_weights) lower to constant-index
             # gathers. Training keeps the O(1)-HLO scan.
             unroll = min(cfg.num_layers, 4) if collect_kv else 1
-            (x, aux), kv = jax.lax.scan(
-                scan_body, (x, jnp.float32(0)), params["blocks"],
-                unroll=unroll,
-            )
+            with jax.named_scope("layer_scan"):
+                (x, aux), kv = jax.lax.scan(
+                    scan_body, (x, jnp.float32(0)), params["blocks"],
+                    unroll=unroll,
+                )
 
         h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return h, aux, kv
@@ -675,27 +683,29 @@ class LM:
         slot = jnp.asarray(slot, jnp.int32)
         kd = cache["k"].dtype
         cache = dict(cache)
-        if ring:
-            cache["k"] = cache["k"].at[:, slot, rows].set(
-                k_all[:, 0, S - keep:].astype(kd))
-            cache["v"] = cache["v"].at[:, slot, rows].set(
-                v_all[:, 0, S - keep:].astype(kd))
-        else:
-            z = jnp.int32(0)
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k_all.astype(kd), (z, slot, z, z, z))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v_all.astype(kd), (z, slot, z, z, z))
-        cache["slot_pos"] = jax.lax.dynamic_update_slice(
-            cache["slot_pos"], sp_row[None, :], (slot, jnp.int32(0)))
-        cache["pos"] = jax.lax.dynamic_update_slice(
-            cache["pos"], jnp.full((1,), S, jnp.int32), (slot,))
+        with jax.named_scope("kv_write"):
+            if ring:
+                cache["k"] = cache["k"].at[:, slot, rows].set(
+                    k_all[:, 0, S - keep:].astype(kd))
+                cache["v"] = cache["v"].at[:, slot, rows].set(
+                    v_all[:, 0, S - keep:].astype(kd))
+            else:
+                z = jnp.int32(0)
+                cache["k"] = jax.lax.dynamic_update_slice(
+                    cache["k"], k_all.astype(kd), (z, slot, z, z, z))
+                cache["v"] = jax.lax.dynamic_update_slice(
+                    cache["v"], v_all.astype(kd), (z, slot, z, z, z))
+            cache["slot_pos"] = jax.lax.dynamic_update_slice(
+                cache["slot_pos"], sp_row[None, :], (slot, jnp.int32(0)))
+            cache["pos"] = jax.lax.dynamic_update_slice(
+                cache["pos"], jnp.full((1,), S, jnp.int32), (slot,))
         if cfg.family == "hybrid":
             cache["mamba"] = jax.tree.map(
                 lambda buf, st: buf.at[:, slot].set(
                     st[:, 0].astype(buf.dtype)),
                 cache["mamba"], mamba_states)
-        logits = self.lm_logits(params, h[:, -1:, :])
+        with jax.named_scope("head"):
+            logits = self.lm_logits(params, h[:, -1:, :])
         return cache, logits
 
     def _xlstm_prefill(self, params, inputs):
@@ -747,8 +757,12 @@ class LM:
 
         slot_pos = cache["slot_pos"]
         # rope tables depend only on pos — compute once, reuse per layer
-        r_sin, r_cos = rope_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
+        with jax.named_scope("rope"):
+            r_sin, r_cos = rope_tables(pos[:, None], cfg.head_dim,
+                                       cfg.rope_theta)
 
+        # named scopes put each part of the step into the op_name of its
+        # device ops, so a profile attributes device time to them
         def block_step(carry, xs):
             x, slot_pos = carry
             if cfg.family == "hybrid":
@@ -758,25 +772,29 @@ class LM:
                 mst = None
             h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
             attn_p = bp["attn"]
-            q = dense_apply(h, attn_p["wq"],
-                            bias=attn_p["bq"] if cfg.qkv_bias else None)
-            k = dense_apply(h, attn_p["wk"],
-                            bias=attn_p["bk"] if cfg.qkv_bias else None)
-            v = dense_apply(h, attn_p["wv"],
-                            bias=attn_p["bv"] if cfg.qkv_bias else None)
-            q = q.reshape(B, 1, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
-            q = apply_rope_tables(q, r_sin, r_cos)
-            k = apply_rope_tables(k, r_sin, r_cos)
-
-            kc, vc, new_slot = cache_insert(kc, vc, slot_pos, k, v, pos,
-                                            ring=ring)
-            attn = decode_attention(
-                q, kc, vc, new_slot, pos, window=cfg.sliding_window,
-            )
-            attn = dense_apply(attn.reshape(B, 1, cfg.attn_dim),
-                               bp["attn"]["wo"])
+            with jax.named_scope("qkv"):
+                q = dense_apply(h, attn_p["wq"],
+                                bias=attn_p["bq"] if cfg.qkv_bias else None)
+                k = dense_apply(h, attn_p["wk"],
+                                bias=attn_p["bk"] if cfg.qkv_bias else None)
+                v = dense_apply(h, attn_p["wv"],
+                                bias=attn_p["bv"] if cfg.qkv_bias else None)
+                q = q.reshape(B, 1, cfg.num_heads, cfg.head_dim)
+                k = k.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
+                v = v.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
+            with jax.named_scope("rope"):
+                q = apply_rope_tables(q, r_sin, r_cos)
+                k = apply_rope_tables(k, r_sin, r_cos)
+            with jax.named_scope("kv_write"):
+                kc, vc, new_slot = cache_insert(kc, vc, slot_pos, k, v, pos,
+                                                ring=ring)
+            with jax.named_scope("attention"):
+                attn = decode_attention(
+                    q, kc, vc, new_slot, pos, window=cfg.sliding_window,
+                )
+            with jax.named_scope("o_proj"):
+                attn = dense_apply(attn.reshape(B, 1, cfg.attn_dim),
+                                   bp["attn"]["wo"])
             if cfg.family == "hybrid":
                 m_out, new_mst = ssm_mod.mamba_step(
                     bp["mamba"], h[:, 0, :], mst)
@@ -785,14 +803,15 @@ class LM:
                 new_mst = None
                 mixer = attn
             x = x + mixer
-            h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-            if cfg.num_experts:
-                y, _ = moe_apply(bp["moe"], h2, top_k=cfg.moe_top_k,
-                                 capacity_factor=cfg.capacity_factor)
-            elif cfg.d_ff:
-                y = ffn_apply(bp["mlp"], h2, cfg.ffn_type)
-            else:
-                y = jnp.zeros_like(x)
+            with jax.named_scope("mlp"):
+                h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps)
+                if cfg.num_experts:
+                    y, _ = moe_apply(bp["moe"], h2, top_k=cfg.moe_top_k,
+                                     capacity_factor=cfg.capacity_factor)
+                elif cfg.d_ff:
+                    y = ffn_apply(bp["mlp"], h2, cfg.ffn_type)
+                else:
+                    y = jnp.zeros_like(x)
             x = x + y
             ys = (kc, vc, new_mst) if cfg.family == "hybrid" else (kc, vc)
             return (x, new_slot), ys
@@ -804,9 +823,10 @@ class LM:
             xs = (params["blocks"], cache["k"], cache["v"])
         # shallow stacks: unroll the layer scan (no while-loop overhead at
         # decode); deep stacks keep the O(1)-HLO scan
-        (x, new_slot_pos), ys = jax.lax.scan(
-            block_step, (x, slot_pos), xs,
-            unroll=min(cfg.num_layers, 4))
+        with jax.named_scope("layer_scan"):
+            (x, new_slot_pos), ys = jax.lax.scan(
+                block_step, (x, slot_pos), xs,
+                unroll=min(cfg.num_layers, 4))
         if cfg.family == "hybrid":
             new_k, new_v, new_mamba = ys
             cache = {**cache, "mamba": new_mamba}
@@ -815,8 +835,9 @@ class LM:
         cache = {**cache, "k": new_k, "v": new_v, "slot_pos": new_slot_pos,
                  "pos": pos + 1}
 
-        h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = self.lm_logits(params, h)
+        with jax.named_scope("head"):
+            h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = self.lm_logits(params, h)
         return cache, logits
 
     def decode_many(self, params, cache, tokens: jnp.ndarray,
@@ -858,9 +879,12 @@ class LM:
         def step(carry, key):
             cache, tok = carry
             cache, logits = self.decode_step(params, cache, tok)
-            nxt = sampler(logits) if key is None else sampler(logits, key)
+            with jax.named_scope("sample"):
+                nxt = sampler(logits) if key is None else sampler(logits,
+                                                                  key)
             if with_flags:
-                ok = jnp.isfinite(logits).all(axis=(-2, -1))     # (B,)
+                with jax.named_scope("health"):
+                    ok = jnp.isfinite(logits).all(axis=(-2, -1))  # (B,)
                 return (cache, nxt), (nxt, ok)
             return (cache, nxt), nxt
 

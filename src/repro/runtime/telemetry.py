@@ -47,14 +47,26 @@ Metric-name taxonomy (dots group the subsystem, labels split series):
   prune.iterations_total / prune.recoveries_total  counters  ADMM loop
   prune.loss / prune.residual / prune.rho          gauges
 
-Span taxonomy (``name`` field of trace records): ``request`` is the
-root span per request (enqueue → terminal), with child events/spans
-``enqueue``, ``admit`` (admission + slot prefill; its end is the
-first-token time), ``first_token``, ``decode_chunk`` (one per micro-
-chunk, engine-wide, listing the slots it advanced), and exactly one
-terminal event per request — ``retire`` | ``shed`` | ``timeout`` |
-``cancelled`` | ``failed`` | ``quarantine`` — matching the request's
-``Result.status``.
+Span taxonomy (``name`` field of trace records): per request, the
+events ``enqueue``, ``first_token`` and exactly one terminal ``retire``
+carrying the request's ``Result.status``. ``ContinuousEngine``'s serve
+loop is tiled by top-level spans, one per phase of an iteration:
+``reap`` (dead queued or live requests), ``admit`` (slot prefill; its
+end is the first-token time; ``admit.failed`` where the first logits
+were not finite) with children ``admit.dispatch`` and ``admit.sync``,
+``arrival_wait`` (sleeping toward the next arrival, one
+span a stretch), ``fault_hook``, ``decode_chunk`` (one per micro-chunk,
+engine-wide) with children ``decode_chunk.prep`` / ``.dispatch`` /
+``.sync``, ``absorb`` (host bookkeeping of what a program returned) and
+``emit`` (a result handed to the consumer, until the loop resumes).
+``ServeEngine`` and ``SpeculativeEngine`` record batch-level ``prefill``
+and ``decode_chunk`` spans after the fact.
+
+Every span opened live (``Tracer.span`` / ``begin`` / ``Timeline``) is
+also a ``jax.profiler.TraceAnnotation`` of the same name for its
+lifetime: while a profile is recorded it lands on the host's python
+line of the xplane, on the device trace's clock, beside the device ops.
+``span_record`` (timed after the fact) has no annotation.
 """
 
 from __future__ import annotations
@@ -76,6 +88,7 @@ __all__ = [
     "Span",
     "Telemetry",
     "TRACE_SCHEMA_VERSION",
+    "Timeline",
     "Tracer",
     "default_bucket_edges",
     "get_registry",
@@ -334,6 +347,8 @@ class Span:
     name: str
     t_start: float
     attrs: Dict[str, Any]
+    annotation: Any = None            # the live profiler annotation
+    t_end: Optional[float] = None     # set by ``Tracer.stop``
 
 
 class Tracer:
@@ -368,6 +383,9 @@ class Tracer:
         self._next_id = 1
         self._stack: List[int] = []
         self._lock = threading.Lock()
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- emission ----------------------------------------------------------
 
@@ -389,19 +407,33 @@ class Tracer:
         })
 
     def begin(self, name: str, parent: Optional[int] = None,
-              **attrs: Any) -> Span:
+              ts: Optional[float] = None, **attrs: Any) -> Span:
+        """Open a span at ``ts`` (default: now) and enter its profiler
+        annotation."""
         with self._lock:
             sid = self._next_id
             self._next_id += 1
         if parent is None and self._stack:
             parent = self._stack[-1]
+        annotation = self._annotation(name)
+        annotation.__enter__()
         return Span(span_id=sid, parent_id=parent, name=name,
-                    t_start=self.clock(), attrs=dict(attrs))
+                    t_start=self.clock() if ts is None else ts,
+                    attrs=dict(attrs), annotation=annotation)
 
-    def end(self, span: Span, **attrs: Any) -> float:
-        """Close a span; returns its duration (clock units)."""
-        t_end = self.clock()
-        dur = t_end - span.t_start
+    def stop(self, span: Span, ts: Optional[float] = None) -> None:
+        """Stamp a span's end at ``ts`` (default: now) and leave its
+        annotation; its record waits for ``end``."""
+        if span.t_end is None:
+            span.t_end = self.clock() if ts is None else ts
+            span.annotation.__exit__(None, None, None)
+
+    def end(self, span: Span, ts: Optional[float] = None,
+            **attrs: Any) -> float:
+        """Close a span at ``ts`` (default: now, or where ``stop`` put
+        its end) and write its record; returns its duration."""
+        self.stop(span, ts)
+        dur = span.t_end - span.t_start
         span.attrs.update(attrs)
         self._emit({
             "schema": TRACE_SCHEMA_VERSION,
@@ -441,7 +473,8 @@ class Tracer:
     def span(self, name: str, **attrs: Any) -> Iterator[Span]:
         """Nested-span context manager: children opened inside inherit
         this span as parent (per-tracer stack; engines are single-
-        threaded through their run loop)."""
+        threaded through their run loop). The span is a profiler
+        annotation of the same name for its lifetime."""
         s = self.begin(name, **attrs)
         self._stack.append(s.span_id)
         try:
@@ -457,6 +490,78 @@ class Tracer:
         self.flush()
         if self._owns:
             self._fh.close()
+
+
+class Timeline:
+    """Top-level spans that tile a loop's wall, each tiled in turn by
+    its children.
+
+    ``to(name)`` closes the open span (and its open child) and opens
+    ``name`` at ONE clock reading, so consecutive spans share their
+    boundary and no time falls between them; ``sub(name)`` does the same
+    for the children of the open span. A caller that already took a
+    reading for its own histograms passes it as ``ts``, so span and
+    histogram agree exactly. ``to(..., hold=True)`` ends the closing span
+    but keeps its record until ``release(**attrs)`` adds what was counted
+    after it ended. With no tracer every call returns at once and reads
+    no clock.
+    """
+
+    def __init__(self, tracer: Optional[Tracer]) -> None:
+        self.tracer = tracer
+        self._top: Optional[Span] = None
+        self._child: Optional[Span] = None
+        self._held: Optional[Span] = None
+
+    def to(self, name: str, ts: Optional[float] = None, *,
+           hold: bool = False, **attrs: Any) -> None:
+        tr = self.tracer
+        if tr is None:
+            return
+        ts = tr.clock() if ts is None else ts
+        self._close(ts, hold)
+        self._top = tr.begin(name, ts=ts, **attrs)
+
+    def sub(self, name: str, ts: Optional[float] = None,
+            **attrs: Any) -> None:
+        tr = self.tracer
+        if tr is None:
+            return
+        ts = tr.clock() if ts is None else ts
+        if self._child is not None:
+            tr.end(self._child, ts)
+        self._child = tr.begin(name, parent=self._top.span_id, ts=ts,
+                               **attrs)
+
+    def rename(self, name: str) -> None:
+        """Give the open top-level span's record another name (its
+        profiler annotation keeps the one it opened with)."""
+        if self._top is not None:
+            self._top.name = name
+
+    def release(self, **attrs: Any) -> None:
+        if self._held is not None:
+            self.tracer.end(self._held, **attrs)
+            self._held = None
+
+    def close(self) -> None:
+        if self.tracer is not None:
+            self._close(self.tracer.clock(), False)
+            self.release()
+
+    def _close(self, ts: float, hold: bool) -> None:
+        tr = self.tracer
+        if self._child is not None:
+            tr.end(self._child, ts)
+            self._child = None
+        if self._top is not None:
+            if hold:
+                self.release()
+                tr.stop(self._top, ts)
+                self._held = self._top
+            else:
+                tr.end(self._top, ts)
+            self._top = None
 
 
 def read_trace(path: str) -> List[Dict[str, Any]]:

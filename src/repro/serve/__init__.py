@@ -128,13 +128,18 @@ status state machine above:
     is nothing else to record).
   * ``decode_chunk`` spans carry ``busy``/``steps``/``batch`` per
     micro-chunk, so run occupancy is recomputable from the trace alone.
+  * the continuous engine's loop is tiled by top-level phase spans
+    (``reap``, ``admit``, ``arrival_wait``, ``fault_hook``,
+    ``decode_chunk``, ``absorb``, ``emit``); ``admit`` and
+    ``decode_chunk`` split into ``.dispatch`` / ``.sync`` children
+    (``decode_chunk.prep`` first), so host sync and host work part.
 
 Trace timestamps are on the ENGINE clock — the one ``arrivals`` and
 ``deadline`` use — so TTFT / TPOT / queue-wait recomputed offline from
 the trace equal the registry's histograms exactly (the acceptance test
 in ``tests/test_telemetry.py`` and the ``BENCH_telemetry`` gate hold
-this). Telemetry records only at existing host sync points: emitted
-tokens are bit-identical with it on or off, and the engines' legacy
+this). Telemetry only reads the clock and writes records on the host:
+emitted tokens are bit-identical with it on or off, and the engines' legacy
 ``.stats`` dicts are compat views over the same registry counters.
 """
 

@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.models.transformer import LM
 from repro.runtime.profiler import get_profiler
-from repro.runtime.telemetry import MetricsRegistry, Telemetry
+from repro.runtime.telemetry import MetricsRegistry, Telemetry, Timeline
 from repro.serve.sampler import (
     fold_key_grid,
     greedy_sample,
@@ -590,10 +590,15 @@ class ContinuousEngine:
         Trace timestamps are on the ENGINE clock (the same one
         ``arrivals``/``deadline`` use — the tracer's clock is rebound
         for the run), so every latency in the registry is recomputable
-        offline from the trace alone. None = metrics land in a private
-        per-run registry (they still back ``stats``) and nothing is
-        traced; all recording happens at existing host sync points, so
-        emitted tokens are bit-identical with telemetry on or off.
+        offline from the trace alone. Every phase of a loop iteration
+        runs inside one top-level span (``reap``, ``admit``,
+        ``arrival_wait``, ``fault_hook``, ``decode_chunk``, ``absorb``,
+        ``emit``; see ``runtime/telemetry.py``), each also a profiler
+        annotation, so a device profile names what the host did in every
+        idle gap. None = metrics land in a private per-run registry (they
+        still back ``stats``) and nothing is traced; spans only read the
+        clock and write records on the host, so emitted tokens are
+        bit-identical with telemetry on or off.
         """
         if model.config.family == "ssm":
             raise NotImplementedError(
@@ -623,21 +628,26 @@ class ContinuousEngine:
         self._capacity, self._ring = spec.capacity, spec.ring
         self.stats: Dict[str, Any] = {}
 
+        def admitted(cache, logits, tok, slot, sample):
+            with jax.named_scope("sample"):
+                first = sample(logits)                         # (1, 1)
+                tok = jax.lax.dynamic_update_slice(
+                    tok, first, (jnp.asarray(slot, jnp.int32),
+                                 jnp.int32(0)))
+            with jax.named_scope("health"):
+                ok = jnp.isfinite(logits).all()
+            return cache, tok, first, ok
+
         def admit_greedy(p, cache, tok, prompt, slot):
             cache, logits = model.prefill_into_slot(p, cache, prompt, slot,
                                                     flash=flash)
-            first = greedy_sample(logits)                      # (1, 1)
-            tok = jax.lax.dynamic_update_slice(
-                tok, first, (jnp.asarray(slot, jnp.int32), jnp.int32(0)))
-            return cache, tok, first, jnp.isfinite(logits).all()
+            return admitted(cache, logits, tok, slot, greedy_sample)
 
         def admit_temp(p, cache, tok, prompt, slot, key, temp):
             cache, logits = model.prefill_into_slot(p, cache, prompt, slot,
                                                     flash=flash)
-            first = temperature_sample(logits, key, temp)
-            tok = jax.lax.dynamic_update_slice(
-                tok, first, (jnp.asarray(slot, jnp.int32), jnp.int32(0)))
-            return cache, tok, first, jnp.isfinite(logits).all()
+            return admitted(cache, logits, tok, slot,
+                            lambda lg: temperature_sample(lg, key, temp))
 
         # decode chunks carry per-slot per-step finite-logit flags: the
         # NaN guard the scheduler quarantines on (observation only —
@@ -773,155 +783,188 @@ class ContinuousEngine:
         if tel is None:
             reg.clock = now
 
-        while not sched.done:
-            t = now()
-            # ---- reap dead requests before they cost anything -------------
-            for order, r, status in sched.reap_queue(t):
-                yield finish(order, r.uid, [], status, t=t)
-            # ---- admit arrived requests into free slots -------------------
-            for st in sched.ready_admissions(t):
-                r = st.request
-                t_adm = now()
-                prompt = r.prompt[None, ...]
-                if r.temperature is not None and r.temperature > 0:
-                    row_key, self._key = request_key(r.seed, self._key)
-                    self._slot_keys[st.slot] = np.asarray(row_key)
-                    k = jax.random.fold_in(row_key, 0)   # token index 0
-                    cache, tok, first, ok = self._admit_temp(
-                        self.params, cache, tok, prompt, st.slot, k,
-                        float(r.temperature))
-                else:
-                    cache, tok, first, ok = self._admit_greedy(
-                        self.params, cache, tok, prompt, st.slot)
-                if not bool(np.asarray(ok)):
-                    # poisoned from the first logits: the slot's KV rows
-                    # already hold NaN — quarantine the lane immediately
-                    sched.table.quarantine(st.slot)
-                    yield finish(st.order, r.uid, [], "failed", t=now())
+        # every phase of an iteration runs inside one top-level span,
+        # consecutive spans sharing their boundary reading (a no-op
+        # without a tracer: no span, no extra clock reading)
+        tl = Timeline(tracer)
+
+        def emit(items):
+            # the consumer's time: from each yield until the loop resumes
+            for item in items:
+                tl.to("emit", uid=item[1].uid)
+                yield item
+
+        try:
+            while not sched.done:
+                # ---- reap dead requests before they cost anything ---------
+                t = now()
+                tl.to("reap", t)
+                yield from emit([finish(order, r.uid, [], status, t=t)
+                                 for order, r, status
+                                 in sched.reap_queue(t)])
+                # ---- admit arrived requests into free slots ---------------
+                for st in sched.ready_admissions(t):
+                    r = st.request
+                    t_adm = now()
+                    tl.to("admit", t_adm, engine=ENG, uid=r.uid,
+                          order=st.order, slot=st.slot,
+                          arrival=arr[st.order])
+                    tl.sub("admit.dispatch", t_adm, uid=r.uid)
+                    prompt = r.prompt[None, ...]
+                    if r.temperature is not None and r.temperature > 0:
+                        row_key, self._key = request_key(r.seed, self._key)
+                        self._slot_keys[st.slot] = np.asarray(row_key)
+                        k = jax.random.fold_in(row_key, 0)  # token index 0
+                        cache, tok, first, ok = self._admit_temp(
+                            self.params, cache, tok, prompt, st.slot, k,
+                            float(r.temperature))
+                    else:
+                        cache, tok, first, ok = self._admit_greedy(
+                            self.params, cache, tok, prompt, st.slot)
+                    # the admission's host sync: its health flag and its
+                    # first token (needed for the eos/max_new check before
+                    # the next chunk)
+                    tl.sub("admit.sync", uid=r.uid)
+                    if not bool(np.asarray(ok)):
+                        # poisoned from the first logits: the slot's KV
+                        # rows already hold NaN — quarantine the lane. The
+                        # record is ``admit.failed``, so ``admit`` keeps
+                        # to admissions that produced a first token
+                        t_fail = now()
+                        tl.rename("admit.failed")
+                        tl.to("absorb", t_fail, uid=r.uid)
+                        sched.table.quarantine(st.slot)
+                        yield from emit([finish(st.order, r.uid, [],
+                                                "failed", t=t_fail)])
+                        continue
+                    first_tok = int(np.asarray(first)[0, 0])
+                    t_first = now()
+                    tl.to("absorb", t_first, uid=r.uid)
+                    t_firsts[st.order] = t_first
+                    # queue wait ends when the admit dispatch began; TTFT
+                    # ends at the first-token host sync just above — both
+                    # measured from the request's scripted/real arrival
+                    h_qwait.observe(t_adm - arr[st.order])
+                    h_ttft.observe(t_first - arr[st.order])
+                    if tracer is not None:
+                        tracer.event("first_token", engine=ENG, uid=r.uid,
+                                     order=st.order, ts=t_first,
+                                     arrival=arr[st.order])
+                    if st.push([first_tok]):
+                        sched.table.retire(st.slot)
+                        yield from emit([finish(st.order, r.uid,
+                                                st.emitted, "ok",
+                                                t=t_first)])
+                # ---- reap live slots whose deadline/cancel fired ----------
+                t_reap = now()
+                tl.to("reap", t_reap)
+                yield from emit([finish(st.order, st.request.uid, st.emitted,
+                                        st.status, t=t_reap)
+                                 for st in sched.reap_active(t_reap)])
+
+                if not sched.table.active:
+                    if sched.table.num_free == 0 and sched.pending:
+                        # every lane is quarantined and requests still
+                        # queue: nothing can ever admit — fail the backlog
+                        # typed instead of spinning forever
+                        t_fail = now()
+                        tl.to("reap", t_fail)
+                        yield from emit([finish(order, r.uid, [], status,
+                                                t=t_fail)
+                                         for order, r, status
+                                         in sched.fail_pending()])
+                        break
+                    nxt = sched.next_arrival()
+                    if nxt is None:
+                        break
+                    t = now()
+                    if nxt > t:
+                        # sleep toward the next arrival, one span a stretch,
+                        # reaping the queue between sleeps. Real clock:
+                        # steps of at most 50 ms; injected clock: yield
+                        # briefly instead of busy-spinning (the clock
+                        # advances on its own)
+                        tl.to("arrival_wait", t)
+                        dead: List[Tuple[int, Any, str]] = []
+                        while not dead and t < nxt:
+                            time.sleep(min(nxt - t, 0.05) if clock is None
+                                       else 1e-4)
+                            t = now()
+                            dead = sched.reap_queue(t)
+                        yield from emit([finish(order, r.uid, [], status,
+                                                t=t)
+                                         for order, r, status in dead])
                     continue
-                # the admission's one host sync: the first token (needed
-                # for the eos/max_new check before the next chunk)
-                first_tok = int(np.asarray(first)[0, 0])
-                t_first = now()
-                t_firsts[st.order] = t_first
-                # queue wait ends when the admit dispatch began; TTFT
-                # ends at the first-token host sync just above — both
-                # measured from the request's scripted/real arrival
-                h_qwait.observe(t_adm - arr[st.order])
-                h_ttft.observe(t_first - arr[st.order])
-                if tracer is not None:
-                    tracer.span_record(
-                        "admit", ts=t_adm, dur=t_first - t_adm, engine=ENG,
-                        uid=r.uid, order=st.order, slot=st.slot,
-                        arrival=arr[st.order])
-                    tracer.event("first_token", engine=ENG, uid=r.uid,
-                                 order=st.order, ts=t_first,
-                                 arrival=arr[st.order])
-                if st.push([first_tok]):
-                    sched.table.retire(st.slot)
-                    yield finish(st.order, r.uid, st.emitted, "ok",
-                                 t=t_first)
-            # ---- reap live slots whose deadline/cancel fired --------------
-            t_reap = now()
-            for st in sched.reap_active(t_reap):
-                yield finish(st.order, st.request.uid, st.emitted, st.status,
-                             t=t_reap)
 
-            if not sched.table.active:
-                if sched.table.num_free == 0 and sched.pending:
-                    # every lane is quarantined and requests still queue:
-                    # nothing can ever admit — fail the backlog typed
-                    # instead of spinning forever
-                    t_fail = now()
-                    for order, r, status in sched.fail_pending():
-                        yield finish(order, r.uid, [], status, t=t_fail)
-                    break
-                nxt = sched.next_arrival()
-                if nxt is None:
-                    break
-                wait = nxt - now()
-                if wait > 0:
-                    # real clock: sleep toward the next arrival; injected
-                    # clock: yield briefly instead of busy-spinning (the
-                    # clock advances on its own)
-                    time.sleep(min(wait, 0.05) if clock is None else 1e-4)
-                continue
+                # ---- chaos seam: deterministic cache-level fault injection
+                if self.fault_hook is not None:
+                    tl.to("fault_hook")
+                    injected = self.fault_hook(cache, sched)
+                    if injected is not None:
+                        cache = injected
 
-            # ---- chaos seam: deterministic cache-level fault injection ----
-            if self.fault_hook is not None:
-                injected = self.fault_hook(cache, sched)
-                if injected is not None:
-                    cache = injected
-
-            # ---- one decode micro-chunk -----------------------------------
-            t_chunk = now()
-            K = sched.chunk_len()
-            n_active = len(sched.table.active)
-            mask = jnp.asarray(sched.table.active_mask())
-            if sched.table.any_stochastic():
-                temps = jnp.asarray(sched.table.temperatures())
-                # step s of slot b draws from fold_in(row_key_b, e_b + s)
-                # where e_b is the slot's own emitted count — the stream
-                # follows the REQUEST, not the engine's chunk clock
-                offsets = np.zeros((self.batch_size,), np.int32)
-                for slot, st in sched.table.active.items():
-                    offsets[slot] = len(st.emitted)
-                keys = fold_key_grid(jnp.asarray(self._slot_keys),
-                                     jnp.asarray(offsets), K)
-                cache, toks, flags = self._chunk_temp(
-                    self.params, cache, tok, mask, temps, keys, K)
-            else:
-                cache, toks, flags = self._chunk_greedy(
-                    self.params, cache, tok, mask, K)
-            tok = toks[:, -1:]
-            # ONE device→host transfer per chunk (tokens + health flags
-            # ride the same sync)
-            toks_np, flags_np = jax.device_get((toks, flags))
-            toks_np = np.asarray(toks_np)
-            t_end = now()
-            dt_chunk = max(t_end - t_chunk, 0.0)
-            if self.straggler is not None:
-                # per-chunk watchdog: the transfer above synced the chunk,
-                # so the delta is real device+host time for these K steps
-                ev = self.straggler.record(sched.chunks, dt_chunk)
-                if ev is not None and tracer is not None:
-                    # flagged chunks land in the trace too — the analyzer
-                    # correlates them with the stalls they explain
-                    tracer.event(
-                        "straggler", ts=t_end, engine=ENG, step=ev.step,
-                        seconds=ev.seconds, median=ev.median,
-                        deviation=ev.deviation)
-            chunk_idx = sched.chunks
-            busy0 = sched.busy_slot_steps
-            finished = sched.absorb_chunk(toks_np, K,
-                                          ok=np.asarray(flags_np))
-            busy_d = sched.busy_slot_steps - busy0
-            c_chunks.inc()
-            c_busy.inc(busy_d)
-            c_total.inc(self.batch_size * K)
-            h_chunk.observe(dt_chunk)
-            prof = get_profiler()
-            if prof.active:
-                # the transfer already synced this chunk: record the
-                # measured wall passively (no extra block, no dispatch)
-                from repro.sparse.tune import m_bucket
-
-                if not hasattr(self, "_cache_nbytes"):  # shape-fixed
-                    self._cache_nbytes = _tree_nbytes(cache)
-                prof.observe("decode_many", dt_chunk,
-                             scheme="engine:continuous",
-                             bucket=m_bucket(self.batch_size),
-                             nbytes=self._cache_nbytes * K)
-            if tracer is not None:
+                # ---- one decode micro-chunk -------------------------------
+                t_chunk = now()
+                tl.to("decode_chunk", t_chunk, engine=ENG,
+                      chunk=sched.chunks)
+                tl.sub("decode_chunk.prep", t_chunk)
+                K = sched.chunk_len()
+                n_active = len(sched.table.active)
+                mask = jnp.asarray(sched.table.active_mask())
+                if sched.table.any_stochastic():
+                    temps = jnp.asarray(sched.table.temperatures())
+                    # step s of slot b draws from fold_in(row_key_b, e_b + s)
+                    # where e_b is the slot's own emitted count — the stream
+                    # follows the REQUEST, not the engine's chunk clock
+                    offsets = np.zeros((self.batch_size,), np.int32)
+                    for slot, st in sched.table.active.items():
+                        offsets[slot] = len(st.emitted)
+                    keys = fold_key_grid(jnp.asarray(self._slot_keys),
+                                         jnp.asarray(offsets), K)
+                    run, args = self._chunk_temp, (temps, keys, K)
+                else:
+                    run, args = self._chunk_greedy, (K,)
+                tl.sub("decode_chunk.dispatch")
+                cache, toks, flags = run(self.params, cache, tok, mask,
+                                         *args)
+                tok = toks[:, -1:]
+                # ONE device→host transfer per chunk (tokens + health flags
+                # ride the same sync)
+                tl.sub("decode_chunk.sync")
+                toks_np, flags_np = jax.device_get((toks, flags))
+                toks_np = np.asarray(toks_np)
+                t_end = now()
+                # the chunk's record waits for the busy count absorb makes
+                tl.to("absorb", t_end, hold=True)
+                dt_chunk = max(t_end - t_chunk, 0.0)
+                if self.straggler is not None:
+                    # per-chunk watchdog: the transfer above synced the
+                    # chunk, so the delta is real device+host time
+                    ev = self.straggler.record(sched.chunks, dt_chunk)
+                    if ev is not None and tracer is not None:
+                        # flagged chunks land in the trace too — the
+                        # analyzer correlates them with the stalls
+                        tracer.event(
+                            "straggler", ts=t_end, engine=ENG, step=ev.step,
+                            seconds=ev.seconds, median=ev.median,
+                            deviation=ev.deviation)
+                busy0 = sched.busy_slot_steps
+                finished = sched.absorb_chunk(toks_np, K,
+                                              ok=np.asarray(flags_np))
+                busy_d = sched.busy_slot_steps - busy0
+                c_chunks.inc()
+                c_busy.inc(busy_d)
+                c_total.inc(self.batch_size * K)
+                h_chunk.observe(dt_chunk)
                 # busy/steps/batch make per-chunk (and run-aggregate)
                 # occupancy recomputable from the trace alone
-                tracer.span_record(
-                    "decode_chunk", ts=t_chunk, dur=dt_chunk, engine=ENG,
-                    chunk=chunk_idx, steps=K, active=n_active,
-                    busy=busy_d, batch=self.batch_size)
-            for st in finished:
-                yield finish(st.order, st.request.uid, st.emitted, st.status,
-                             t=t_end)
+                tl.release(steps=K, active=n_active, busy=busy_d,
+                           batch=self.batch_size)
+                yield from emit([finish(st.order, st.request.uid,
+                                        st.emitted, st.status, t=t_end)
+                                 for st in finished])
+        finally:
+            tl.close()
 
         c_quar.inc(len(sched.table.quarantined))
         busy = c_busy.value - base["busy"]

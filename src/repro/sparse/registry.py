@@ -69,6 +69,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -230,6 +231,19 @@ def _plan_label(pt: PackedTensor, kind: str, M: int) -> str:
     return plan.to_str() if plan is not None else "heuristic"
 
 
+def _scoped(fn: Callable, scheme: str, impl: str) -> Callable:
+    """``fn`` with its ops under ``packed/<scheme>/<impl>`` in the
+    program's op_name metadata, so a profile names the plan that ran."""
+    scope = f"packed/{scheme}/{impl}"
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return run
+
+
 def _plan_key(pt: PackedTensor, M: int, dtype, has_bias: bool,
               activation: Optional[str], interpret: bool, kind: str) -> Tuple:
     bufs = tuple((n, tuple(b.shape), str(b.dtype))
@@ -316,7 +330,7 @@ def _dense_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
         y = jnp.dot(a, w, preferred_element_type=jnp.float32)
         return apply_epilogue(y, bias, activation).astype(x.dtype)
 
-    return fn
+    return _scoped(fn, pt.scheme, "xla")
 
 
 def _dense_matmul(x, pt, bias=None, *, activation=None, interpret=None):
@@ -506,7 +520,7 @@ def _tile_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
                 y = jnp.transpose(y, (1, 0, 2)).reshape(M, P)
             return apply_epilogue(y, bias, activation).astype(x_in.dtype)
 
-        return fn
+        return _scoped(fn, pt.scheme, impl)
 
     bm = exec_plan.block_m or _row_block(M)
     if bm > M:                    # don't pad M past one row tile
@@ -521,7 +535,7 @@ def _tile_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
                           activation=activation, grid_order=go)
         return y[:M] if pad else y
 
-    return fn
+    return _scoped(fn, pt.scheme, "pallas")
 
 
 def _tile_matmul(x, pt, bias=None, *, activation=None, interpret=None):
@@ -646,7 +660,7 @@ def _column_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
                     preferred_element_type=jnp.float32)
             return apply_epilogue(y, bias, activation).astype(x_in.dtype)
 
-        return fn
+        return _scoped(fn, pt.scheme, impl)
 
     bp = (exec_plan.block_p
           or int(pt.meta_dict.get("block_p", 0))
@@ -666,7 +680,7 @@ def _column_plan(pt, M, has_bias, activation, interpret, exec_plan=None):
                          grid_order=go)
         return y[:M] if pad else y
 
-    return fn
+    return _scoped(fn, pt.scheme, "pallas")
 
 
 def _column_matmul(x, pt, bias=None, *, activation=None, interpret=None):
@@ -776,10 +790,11 @@ def _pattern_conv(x, pt, bias=None, *, activation=None, interpret=None):
         plan = _tune.Plan("xla") if interpret else _tune.Plan("pallas")
     # no conv plan cache exists: dispatch_conv's _count_dispatch already
     # counts traced conv dispatches, so no plan_build event here
-    xg = _gather_taps(x, pt.buf("taps"))
-    run = conv_gemm_runner(pt, plan, interpret=interpret,
-                           activation=activation)
-    y = run(xg, pt.buf("w_packed"), bias)
+    with jax.named_scope(f"packed/{pt.scheme}/{plan.impl}"):
+        xg = _gather_taps(x, pt.buf("taps"))
+        run = conv_gemm_runner(pt, plan, interpret=interpret,
+                               activation=activation)
+        y = run(xg, pt.buf("w_packed"), bias)
     return y.reshape(B, H, W, -1)
 
 
