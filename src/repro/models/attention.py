@@ -644,26 +644,35 @@ def cache_insert_chunk(
 
 
 def cache_insert(
-    k_cache: jnp.ndarray,            # (B, C, KV, hd)
+    k_cache: jnp.ndarray,            # (B, C, KV, hd), or (L, B, C, KV, hd)
     v_cache: jnp.ndarray,
     slot_pos: jnp.ndarray,           # (B, C)
     k_new: jnp.ndarray,              # (B, 1, KV, hd)
     v_new: jnp.ndarray,
-    pos: jnp.ndarray,                # (B,) int32
+    pos,                             # (B,) int32, or (layer, (B,) int32)
     *,
     ring: bool,
 ):
     """Insert one position into the cache (ring: slot = pos % C).
 
     Per-batch scatter into the target slot: touches B·KV·hd elements
-    instead of blending over the whole (B, C, KV, hd) cache — the decode
-    scan carries the buffers through unchanged except for the one slot,
-    which is what lets XLA update them in place step over step.
+    instead of blending over the whole (B, C, KV, hd) cache. ``pos`` given
+    as ``(layer, pos)`` names one layer of a layer-stacked
+    (L, B, C, KV, hd) cache, and the rows land at ``[layer, b, slot]``.
+    That is the decode step's form: ``LM.decode_many``'s scan over steps
+    and ``LM.decode_step``'s scan over layers both carry the stacked
+    buffers, so each layer's insert is a one-row scatter that XLA does in
+    place, and no layer's slab is copied out of the stack or back into it.
     """
-    C = k_cache.shape[1]
-    slot = (pos % C) if ring else pos                         # (B,)
-    b = jnp.arange(k_cache.shape[0])
-    k_cache = k_cache.at[b, slot].set(k_new[:, 0].astype(k_cache.dtype))
-    v_cache = v_cache.at[b, slot].set(v_new[:, 0].astype(v_cache.dtype))
+    layer = ()                                                # index prefix
+    if isinstance(pos, tuple):
+        layer, pos = pos[:1], pos[1]
+    b = jnp.arange(pos.shape[0])
+    _, rows = chunk_rows(pos, 1, k_cache.shape[-3], ring)
+    slot = rows[:, 0]                                         # (B,)
+    k_cache = k_cache.at[layer + (b, slot)].set(
+        k_new[:, 0].astype(k_cache.dtype))
+    v_cache = v_cache.at[layer + (b, slot)].set(
+        v_new[:, 0].astype(v_cache.dtype))
     slot_pos = slot_pos.at[b, slot].set(pos)
     return k_cache, v_cache, slot_pos

@@ -741,7 +741,16 @@ class LM:
     # ------------------------------------------------------------ decode step
 
     def decode_step(self, params, cache: Dict[str, Any], tokens: jnp.ndarray):
-        """One decode step. tokens: (B, 1) ids or (B, 1, D) embeddings."""
+        """One decode step. tokens: (B, 1) ids or (B, 1, D) embeddings.
+
+        The layer scan runs over ``(blocks, layer index)`` and carries the
+        stacked (L, B, C, KV, hd) K/V buffers: layer ``l`` writes its new
+        row at ``[l, b, slot]`` (``cache_insert``, one row per layer,
+        in place in the carried stack) and attends over layer ``l`` of the
+        same buffers, so no layer's slab is sliced out of the stack as a
+        scan input or rebuilt as a scan output. The hybrid family's mamba
+        state, a few KB a layer, is scanned over as input and output.
+        """
         cfg = self.config
         if cfg.family == "ssm":
             return self._xlstm_decode(params, cache, tokens)
@@ -749,13 +758,12 @@ class LM:
         x = self.embed_inputs(params, tokens)          # (B, 1, D)
         B = x.shape[0]
         pos = cache["pos"]                              # (B,)
-        spec = self.cache_spec(cache["k"].shape[2])
-        # note: capacity C == cache["k"].shape[2]; ring iff a sliding window
-        ring = cfg.sliding_window is not None and (
-            cache["k"].shape[2] <= cfg.sliding_window
-        )
+        ring = self._cache_ring(cache)
 
-        slot_pos = cache["slot_pos"]
+        # the new position's slot, and so the mask row it leaves, is the
+        # same in every layer: written once, outside the layer scan
+        _, rows = chunk_rows(pos, 1, cache["k"].shape[2], ring)
+        slot_pos = cache["slot_pos"].at[jnp.arange(B), rows[:, 0]].set(pos)
         # rope tables depend only on pos — compute once, reuse per layer
         with jax.named_scope("rope"):
             r_sin, r_cos = rope_tables(pos[:, None], cfg.head_dim,
@@ -764,11 +772,11 @@ class LM:
         # named scopes put each part of the step into the op_name of its
         # device ops, so a profile attributes device time to them
         def block_step(carry, xs):
-            x, slot_pos = carry
+            x, k_all, v_all = carry
             if cfg.family == "hybrid":
-                bp, kc, vc, mst = xs
+                bp, layer, mst = xs
             else:
-                bp, kc, vc = xs
+                bp, layer = xs
                 mst = None
             h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
             attn_p = bp["attn"]
@@ -786,11 +794,13 @@ class LM:
                 q = apply_rope_tables(q, r_sin, r_cos)
                 k = apply_rope_tables(k, r_sin, r_cos)
             with jax.named_scope("kv_write"):
-                kc, vc, new_slot = cache_insert(kc, vc, slot_pos, k, v, pos,
-                                                ring=ring)
+                # the slot_pos it returns is the one written above
+                k_all, v_all, _ = cache_insert(k_all, v_all, slot_pos, k, v,
+                                               (layer, pos), ring=ring)
             with jax.named_scope("attention"):
                 attn = decode_attention(
-                    q, kc, vc, new_slot, pos, window=cfg.sliding_window,
+                    q, k_all[layer], v_all[layer], slot_pos, pos,
+                    window=cfg.sliding_window,
                 )
             with jax.named_scope("o_proj"):
                 attn = dense_apply(attn.reshape(B, 1, cfg.attn_dim),
@@ -813,26 +823,22 @@ class LM:
                 else:
                     y = jnp.zeros_like(x)
             x = x + y
-            ys = (kc, vc, new_mst) if cfg.family == "hybrid" else (kc, vc)
-            return (x, new_slot), ys
+            return (x, k_all, v_all), new_mst
 
+        layers = jnp.arange(cfg.num_layers)
         if cfg.family == "hybrid":
-            xs = (params["blocks"], cache["k"], cache["v"],
-                  cache["mamba"])
+            xs = (params["blocks"], layers, cache["mamba"])
         else:
-            xs = (params["blocks"], cache["k"], cache["v"])
+            xs = (params["blocks"], layers)
         # shallow stacks: unroll the layer scan (no while-loop overhead at
         # decode); deep stacks keep the O(1)-HLO scan
         with jax.named_scope("layer_scan"):
-            (x, new_slot_pos), ys = jax.lax.scan(
-                block_step, (x, slot_pos), xs,
+            (x, new_k, new_v), new_mamba = jax.lax.scan(
+                block_step, (x, cache["k"], cache["v"]), xs,
                 unroll=min(cfg.num_layers, 4))
         if cfg.family == "hybrid":
-            new_k, new_v, new_mamba = ys
             cache = {**cache, "mamba": new_mamba}
-        else:
-            new_k, new_v = ys
-        cache = {**cache, "k": new_k, "v": new_v, "slot_pos": new_slot_pos,
+        cache = {**cache, "k": new_k, "v": new_v, "slot_pos": slot_pos,
                  "pos": pos + 1}
 
         with jax.named_scope("head"):
@@ -850,7 +856,9 @@ class LM:
         a whole ``num_steps`` block costs ONE XLA dispatch and ONE host
         transfer instead of one of each per token. The KV cache lives in
         the scan carry — XLA reuses (donates) its buffers across steps
-        instead of round-tripping them to the host.
+        instead of round-tripping them to the host — and, inside each
+        step, in the layer scan's carry (``decode_step``), updated one row
+        per layer in place.
 
         tokens: (B, 1) int32 — the first token of the block (e.g. sampled
         from the prefill logits). ``sampler``: jit-compatible
@@ -910,7 +918,8 @@ class LM:
             )
 
     def _cache_ring(self, cache) -> bool:
-        """Mirror decode_step's rule: ring iff a sliding window bounds C."""
+        """Ring iff a sliding window bounds the capacity C (the rule of
+        ``decode_step`` and ``verify_chunk``)."""
         C = cache["k"].shape[2]
         return self.config.sliding_window is not None and \
             C <= self.config.sliding_window

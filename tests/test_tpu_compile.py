@@ -8,10 +8,14 @@ and check that the compiled HLO holds the kernel as a ``tpu_custom_call``.
 
 Shapes are Qwen2-1.5B's (d_model 1536, 12 query / 2 KV heads of 128, d_ff
 8960, vocabulary 151,936, bf16) at a 512-row prefill, and one VGG-16 layer
-for the conv.
+for the conv. The engine's programs compile whole at that width, with the
+vocabulary cut; the decode chunk's compiled HLO is checked for how it
+moves the KV cache.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -169,3 +173,72 @@ def test_continuous_engine_programs(one_chip, monkeypatch, packed):
             _assert_kernel(hlo, name)
     eng._chunk_greedy.lower(p, cache, tok, _spec((batch,), jnp.int32,
                                                  one_chip), 8).compile()
+
+
+def _stacked_cache_ops(hlo: str, row_shape) -> list:
+    """``(opcode, line)`` of each op whose output holds more than one
+    layer of a K/V cache whose per-layer rows are ``row_shape``."""
+    rows = ",".join(map(str, row_shape))
+    pat = re.compile(r"%\S+ = bf16\[([\d,]+)," + rows
+                     + r"\]\{[^}]*\} ([\w-]+)\(")
+    out = []
+    for ln in hlo.splitlines():
+        m = pat.search(ln)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) > 1:
+            out.append((m.group(2), ln.strip()))
+    return out
+
+
+def test_decode_chunk_updates_the_cache_in_place(one_chip, monkeypatch):
+    """``ContinuousEngine``'s decode chunk (8 steps) at Qwen2-1.5B width,
+    8 layers so the 4-way unrolled layer scan loops, 32 slots, dense
+    weights: the stacked K/V cache rides the layer scan's carry. No op
+    outputs a buffer of more than one layer of the cache except the
+    in-place update of the carried stack, and the temporaries that grow
+    with the cache stay below one layer's K and V. (The scan's slices of
+    the weights do not depend on the cache: a second compile at a short
+    cache takes them out of the comparison.)"""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve.engine import ContinuousEngine
+
+    cfg = dataclasses.replace(get_config("qwen2-1.5b"), num_layers=8,
+                              vocab_size=4096)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch = 32
+    spec = lambda a: _spec(a.shape, a.dtype, one_chip)
+
+    def chunk(max_seq):
+        # the engine donates the cache to its chunk program on a TPU only
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            eng = ContinuousEngine(model, params, batch_size=batch,
+                                   max_seq_len=max_seq, packed=False)
+        cache = jax.tree.map(spec, jax.eval_shape(
+            lambda: model.init_cache(batch, max_seq)))
+        compiled = eng._chunk_greedy.lower(
+            jax.tree.map(spec, params), cache,
+            _spec((batch, 1), jnp.int32, one_chip),
+            _spec((batch,), jnp.int32, one_chip), 8).compile()
+        return compiled, cache["k"]
+
+    compiled, k = chunk(1536)
+    hlo = compiled.as_text()
+    ops = _stacked_cache_ops(hlo, k.shape[1:])
+    in_place = [ln for op, ln in ops
+                if op == "fusion" and "aliasing_operands" in ln]
+    assert len(in_place) >= 2, "no in-place update of the K and V stacks"
+    moved = [ln for op, ln in ops
+             if op not in ("parameter", "get-tuple-element", "bitcast",
+                           "scatter", "fusion")
+             or (op == "fusion" and "aliasing_operands" not in ln)]
+    assert not moved, "\n".join(ln[:200] for ln in moved[:8])
+
+    slab = 2 * math.prod(k.shape[1:]) * k.dtype.itemsize
+    short, _ = chunk(128)
+    grown = (compiled.memory_analysis().temp_size_in_bytes
+             - short.memory_analysis().temp_size_in_bytes)
+    assert grown < slab, (grown, slab)
