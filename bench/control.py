@@ -40,7 +40,7 @@ def readings(ctx) -> dict:
     serving.free(served)
     picks = serving.check_sample(w, plan, ctx.seed,
                                  ctx.workload["check"]["requests"])
-    out = serving.served_gaps(served.shapes, ctx.seed, w, plan, picks, mix,
+    out = serving.served_gaps(served, ctx.seed, w, plan, picks, mix,
                               control=True)
     short = serving.short_answers(w)
     limit = ctx.workload["check"]["widest_gap"]

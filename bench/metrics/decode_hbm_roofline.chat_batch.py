@@ -3,7 +3,7 @@ stored and the live rows' KV bytes each step must read, at the chip's
 bandwidth, over the traced decode-chunk programs' device time (model
 step, decode)."""
 
-from bench import readers, work
+from bench import readers
 
 
 def compute(f):
@@ -11,6 +11,6 @@ def compute(f):
     took = sum(m.dur for m, _ in dec)
     if took <= 0:
         return None
-    least = sum(work.decode_step(f.shapes, step).bytes
+    least = sum(f.arch.decode_step(f.shapes, step).bytes
                 for _, steps in dec for step in steps if step)
     return 100.0 * least / f.peaks.hbm_bytes / took

@@ -21,7 +21,8 @@ SLACK = 1e-3             # seconds a program may stand outside its span
 class Facts:
     """Everything one traced run hands to the metric readers."""
 
-    shapes: Any
+    shapes: Any                     # the architecture module's ``Shapes``
+    arch: Any                       # the configuration's architecture module
     peaks: Any
     window: Any                     # serving.Window
     reduced: Optional[tr.Reduced] = None
@@ -153,20 +154,22 @@ def busy_of(f: Facts, programs: List[tr.Event]) -> float:
     return sum(b - a for a, b in tr.union(inner))
 
 
-def kernel_roofline(f: Facts, kernel: str, calls_per_layer: int,
-                    need) -> Optional[float]:
+def kernel_roofline(f: Facts, kernel: str) -> Optional[float]:
     """Percent of its roofline a prefill kernel reached over the traced
     admissions: the least time the chip could take for the work the
-    kernel was asked (``need(S)`` per layer and call, a ``work.Work``),
+    kernel was asked (``arch.prefill_kernels``: calls and work per block),
     over the device time of the kernel's events. Admissions whose kernel
-    count is not ``calls_per_layer`` per layer are left out."""
+    count is not the architecture's calls per block times the blocks are
+    left out; an architecture whose prefill does not run the kernel
+    gives nothing to read."""
     adm = admissions(f)
     per = kernel_events(f, [m for m, _ in adm], kernel)
     least, took = 0.0, 0.0
     for (m, S), evs in zip(adm, per):
-        if len(evs) != calls_per_layer * f.shapes.layers:
+        need = f.arch.prefill_kernels(f.shapes, S).get(kernel)
+        if need is None or len(evs) != need[0] * f.shapes.layers:
             continue
-        least += f.shapes.layers * need(S).seconds(f.peaks)
+        least += f.shapes.layers * need[1].seconds(f.peaks)
         took += sum(e.dur for e in evs)
     return 100.0 * least / took if took > 0 else None
 
@@ -175,12 +178,10 @@ def step_mfu(f: Facts) -> Optional[float]:
     """Percent of the chip's bf16 peak: the operations every traced
     admission and decode chunk required, over the device time in which
     their operations ran."""
-    from bench import work
-
     adm = admissions(f)
     dec = decode_chunks(f)
-    flops = sum(work.prefill(f.shapes, S).flops for _, S in adm)
-    flops += sum(work.decode_token_flops(f.shapes, c)
+    flops = sum(f.arch.prefill(f.shapes, S).flops for _, S in adm)
+    flops += sum(f.arch.decode_token_flops(f.shapes, c)
                  for _, steps in dec for step in steps for c in step)
     busy = busy_of(f, [m for m, _ in adm] + [m for m, _ in dec])
     if busy <= 0:

@@ -1,11 +1,12 @@
 """Serving cells: prune → pack → ``ContinuousEngine.stream`` on one chip.
 
 Set-up (timed as ``setup_s``): the weights of the seed on the device
-(``bench.weights``), the one-shot projection (``greedy_prune``, one leaf
-at a time so that a leaf and its copy are the only doubled bytes), packing
-with the registry's default plans (no tuning), the engine, and a warm-up
-that compiles each prompt bucket's admission program and every decode
-scan length up to ``chunk_steps``.
+(made by the configuration's architecture module, ``bench/archs``), the
+one-shot projection (``greedy_prune``, one leaf at a time so that a leaf
+and its copy are the only doubled bytes), packing with the registry's
+default plans (no tuning), the engine, and a warm-up that compiles each
+prompt bucket's admission program and every decode scan length up to
+``chunk_steps``.
 
 Window: the traffic's requests through ``ContinuousEngine.stream`` on the
 benchmark's clock. An open-loop mix stops arriving at the window's end and
@@ -32,8 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from bench import reference, traffic, weights
-from bench.spec import Shapes, model_config
+from bench import reference, spec, traffic
 
 TRACE_MARK = "bench_clock"
 
@@ -55,7 +55,8 @@ class Served:
     """What set-up leaves for the window."""
 
     engine: Any
-    shapes: Shapes
+    shapes: Any                 # the architecture module's ``Shapes``
+    arch: Any                   # the configuration's architecture module
 
 
 def prune_leafwise(params: Dict[str, Any], pcfg) -> Tuple[Any, Any]:
@@ -99,9 +100,9 @@ def setup(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
     from repro.serve.engine import ContinuousEngine
     from repro.sparse import PrunedArtifact
 
-    shapes = Shapes.of(cfg)
-    mcfg = model_config(cfg)
-    model = build_model(mcfg)
+    arch = spec.load_arch(cfg["arch"])
+    shapes = arch.Shapes.of(cfg)
+    model = build_model(arch.model_config(cfg))
     p = cfg["prune"]
     pcfg = prune_config_for(
         scheme=p["scheme"], rate=p["rate"], iters=1,
@@ -109,7 +110,7 @@ def setup(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         exclude=tuple(DEFAULT_EXCLUDE) + tuple(p["extra_exclude"]))
 
     t = time.perf_counter()
-    params = weights.make_params(shapes, seed)
+    params = arch.make_params(shapes, seed)
     jax.block_until_ready(params)
     log(f"weights made on the device in {time.perf_counter() - t:.3f} s")
     t = time.perf_counter()
@@ -147,7 +148,7 @@ def setup(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
     for lk, n in sorted(plans.items()):
         log(f"plan resolved: {','.join(f'{k}={v}' for k, v in lk)}: "
             f"{int(n)} build(s)")
-    return Served(engine=engine, shapes=shapes)
+    return Served(engine=engine, shapes=shapes, arch=arch)
 
 
 def _paths(tree) -> Dict[str, Any]:
@@ -339,22 +340,25 @@ def pad_bucket(T: int, mix: Dict[str, Any]) -> int:
     return T
 
 
-def served_gaps(shapes: Shapes, seed: int, w: Window, plan: List,
+def served_gaps(served: Served, seed: int, w: Window, plan: List,
                 picks: List[int], mix: Dict[str, Any], *,
                 control: bool = False) -> Dict[str, Any]:
     """Reference gaps of the served tokens of ``picks`` (and, with
-    ``control``, the fp8 control's at the same positions)."""
-    params = weights.make_params(shapes, seed)
+    ``control``, the fp8 control's at the same positions), on the weights
+    of the seed made anew after ``free(served)``."""
+    arch, shapes = served.arch, served.shapes
+    params = arch.make_params(shapes, seed)
     widest, ctrl, tokens = 0.0, 0.0, 0
     for i in picks:
         prompt = plan[i].prompt.tolist()
-        served = w.outcomes[i].tokens
-        pad = pad_bucket(len(prompt) + len(served), mix)
-        g = reference.served_gaps(shapes, params, prompt, served, pad_to=pad)
+        toks = w.outcomes[i].tokens
+        pad = pad_bucket(len(prompt) + len(toks), mix)
+        g = reference.served_gaps(arch, shapes, params, prompt, toks,
+                                  pad_to=pad)
         widest = max(widest, max(g))
-        tokens += len(served)
+        tokens += len(toks)
         if control:
-            c = reference.control_gaps(shapes, params, prompt, served,
+            c = reference.control_gaps(arch, shapes, params, prompt, toks,
                                        pad_to=pad)
             ctrl = max(ctrl, max(c))
     del params
@@ -425,7 +429,7 @@ def run_cell(ctx, end_to_end: Callable[[Window, Dict[str, Any]],
     picks = check_sample(w, plan, ctx.seed, wl["check"]["requests"])
     short = short_answers(w)
     t = time.perf_counter()
-    gaps = served_gaps(served.shapes, ctx.seed, w, plan, picks, mix) \
+    gaps = served_gaps(served, ctx.seed, w, plan, picks, mix) \
         if picks else {"widest_gap": -1.0, "tokens": 0, "requests": 0}
     ctx.log(f"check: {gaps['requests']} requests, {gaps['tokens']} served "
             f"tokens against the reference in "
@@ -443,8 +447,9 @@ def run_cell(ctx, end_to_end: Callable[[Window, Dict[str, Any]],
         lo = mark.start
         hi = mark_end.start if mark_end is not None else max(
             e.end for e in red.ops[red.chips[0]])
-        run.facts = Facts(shapes=served.shapes, peaks=ctx.peaks, window=w,
-                          reduced=red, offset=offset, lo=lo, hi=hi)
+        run.facts = Facts(shapes=served.shapes, arch=served.arch,
+                          peaks=ctx.peaks, window=w, reduced=red,
+                          offset=offset, lo=lo, hi=hi)
         ctx.log(f"trace read in {time.perf_counter() - t:.3f} s: "
                 f"{sum(len(v) for v in red.ops.values())} device operations,"
                 f" {sum(len(v) for v in red.modules.values())} programs")

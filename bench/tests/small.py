@@ -1,7 +1,8 @@
 """A cell cut to a size the CPU runs in seconds, for the tests.
 
-The configuration keeps its file's keys with small widths; the traffic
-mix keeps its kind with short prompts and few tokens.
+The configuration keeps its file's keys with the small widths of its
+architecture module's ``small_config``; the traffic mix keeps its kind
+with short prompts and few tokens.
 """
 
 import time
@@ -10,16 +11,10 @@ import jax
 
 from bench import harness, spec
 
-SMALL_WIDTHS = dict(hidden_size=64, intermediate_size=128,
-                    num_attention_heads=4, num_key_value_heads=2,
-                    num_hidden_layers=2, vocab_size=512)
-
 
 def small_config(name):
-    cfg = dict(spec.load_config(name), **SMALL_WIDTHS)
-    cfg["assumed"] = dict(cfg["assumed"], head_dim=16)
-    cfg["prune"] = dict(cfg["prune"], tile_block=32)
-    return cfg
+    cfg = spec.load_config(name)
+    return spec.load_arch(cfg["arch"]).small_config(cfg)
 
 
 def small_mix(arrivals):

@@ -20,8 +20,8 @@ def ev(name, a, b):
 def facts(ops, modules=(), host=(), lo=0.0, hi=10.0):
     red = tr.Reduced(ops={0: list(ops)}, modules={0: list(modules)},
                      host=sorted(host, key=lambda e: e.start))
-    return readers.Facts(shapes=None, peaks=None, window=None, reduced=red,
-                         lo=lo, hi=hi)
+    return readers.Facts(shapes=None, arch=None, peaks=None, window=None,
+                         reduced=red, lo=lo, hi=hi)
 
 
 def metric(name, f):

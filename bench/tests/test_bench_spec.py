@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` against the files it names and the contract's
 cross-references."""
 
+import functools
 import os
 import re
 
@@ -8,6 +9,10 @@ from bench import spec
 
 B = spec.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+ARCH_INTERFACE = ("Shapes.of", "model_config", "make_params", "_make",
+                  "logits_at", "prefill", "decode_token_flops",
+                  "decode_step", "weight_bytes", "prefill_kernels",
+                  "small_config")
 
 
 def test_files_named_exist():
@@ -19,6 +24,10 @@ def test_files_named_exist():
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
         for k in c["reduced"]:
             assert k in cfg["published"] and cfg[k] != cfg["published"][k]
+        arch = spec.load_arch(cfg["arch"])
+        for fn in ARCH_INTERFACE:
+            assert callable(functools.reduce(getattr, fn.split("."), arch)), (
+                cfg["arch"], fn)
     for w in B["workloads"]:
         wl = spec.load_workload(w["name"])
         assert (wl["config"], wl["traffic"], wl["chips"], wl["why"]) == (

@@ -66,8 +66,9 @@ def test_programs_matched_to_engine_spans():
                ev("jit_slice(2)", 10.29, 10.295),
                ev("jit_scan_decode(3)", 10.41, 10.49)]
     red = tr.Reduced(ops={0: []}, modules={0: modules}, host=[])
-    f = readers.Facts(shapes=None, peaks=None, window=_window(spans, outs),
-                      reduced=red, offset=10.0, lo=10.0, hi=11.0)
+    f = readers.Facts(shapes=None, arch=None, peaks=None,
+                      window=_window(spans, outs), reduced=red, offset=10.0,
+                      lo=10.0, hi=11.0)
     adm = readers.admissions(f)
     assert [(m.name, S) for m, S in adm] == [("jit_admit_greedy(1)", 32)]
     dec = readers.decode_chunks(f)
@@ -95,12 +96,12 @@ def test_recorded_tpu_trace():
 
 
 def test_kernel_roofline_and_step_mfu_from_a_built_trace():
-    """The readers divide the work required by the kernel events found
-    inside each matched admission; admissions with a wrong count of
-    kernel calls are left out."""
+    """The readers divide the work required, as the architecture module
+    counts it, by the kernel events found inside each matched admission;
+    admissions with a wrong count of kernel calls are left out."""
     from bench import peaks, work
     from bench.serving import Outcome
-    from bench.tests.test_bench_work import small_shapes
+    from bench.tests.test_bench_work import QWEN2, small_shapes
 
     s = small_shapes()
     p = peaks.peaks_of("TPU v5 lite")
@@ -121,23 +122,24 @@ def test_kernel_roofline_and_step_mfu_from_a_built_trace():
             for i in range(13)]
     ops += [ev("%flash_attention.3 = x", 0.5, 0.6),
             ev("%flash_attention.3 = x", 0.6, 0.7)]
-    f = readers.Facts(shapes=s, peaks=p, window=_window(spans, outs),
+    f = readers.Facts(shapes=s, arch=QWEN2, peaks=p,
+                      window=_window(spans, outs),
                       reduced=tr.Reduced(ops={0: ops}, modules={0: mods},
                                          host=[]),
                       offset=0.0, lo=0.0, hi=3.0)
-    names = work.BLOCK_GEMMS
+    names = QWEN2.BLOCK_GEMMS
 
     def need(S):
-        return sum((work.gemm(s, n, S) for n in names), work.Work())
+        return sum((QWEN2.gemm(s, n, S) for n in names), work.Work())
 
-    got = readers.kernel_roofline(f, "pattern_gemm", len(names), need)
+    assert QWEN2.prefill_kernels(s, 32)["pattern_gemm"] == (7, need(32))
+    got = readers.kernel_roofline(f, "pattern_gemm")
     assert got == pytest.approx(100 * 2 * need(32).seconds(p) / 0.14)
-    flash = readers.kernel_roofline(
-        f, "flash_attention", 1,
-        lambda S: work.flash_prefill(S, s.heads, s.kv_heads, s.head_dim))
+    flash = readers.kernel_roofline(f, "flash_attention")
     assert flash == pytest.approx(
         100 * 2 * work.flash_prefill(32, 4, 2, 16).seconds(p) / 0.2)
+    assert readers.kernel_roofline(f, "column_gemm") is None
     mfu = readers.step_mfu(f)
     busy = 0.14 + 0.2 + 0.13           # op time inside both admissions
-    flops = work.prefill(s, 32).flops + work.prefill(s, 64).flops
+    flops = QWEN2.prefill(s, 32).flops + QWEN2.prefill(s, 64).flops
     assert mfu == pytest.approx(100 * flops / (busy * p.bf16_flops))

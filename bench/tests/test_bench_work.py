@@ -1,10 +1,16 @@
-"""Required operations and bytes, against hand counts at a small size."""
+"""Required operations and bytes, against hand counts at a small size, and
+each configuration's architecture module against the program's model."""
 
 import dataclasses
+import os
 
 import pytest
 
 from bench import peaks, spec, work
+
+QWEN2 = spec.load_arch("qwen2")
+CONFIGS = sorted(f[:-len(".json")] for f in
+                 os.listdir(os.path.join(spec.BENCH, "configs")))
 
 
 def small_shapes(**kw):
@@ -14,7 +20,7 @@ def small_shapes(**kw):
                 packed=("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                         "lm_head"))
     base.update(kw)
-    return spec.Shapes(**base)
+    return QWEN2.Shapes(**base)
 
 
 def test_packed_gemm_hand_count():
@@ -40,22 +46,22 @@ def test_prefill_and_decode_at_reduced_size():
     gemm_flops = 2 * S * (64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128) // 2
     attn = 4 * 16 * 4 * S * (S + 1) / 2
     head = 2 * 1 * 64 * 512 // 2
-    assert work.prefill(s, S).flops == 2 * (gemm_flops + attn) + head
-    tok = work.decode_token_flops(s, 40)
+    assert QWEN2.prefill(s, S).flops == 2 * (gemm_flops + attn) + head
+    tok = QWEN2.decode_token_flops(s, 40)
     kept = (64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128) // 2
     assert tok == 2 * (2 * kept + 4 * 16 * 4 * 40) + 2 * 64 * 512 // 2
-    step = work.decode_step(s, [40, 10])
-    assert step.flops == work.decode_token_flops(s, 40) + \
-        work.decode_token_flops(s, 10)
+    step = QWEN2.decode_step(s, [40, 10])
+    assert step.flops == QWEN2.decode_token_flops(s, 40) + \
+        QWEN2.decode_token_flops(s, 10)
     kv = 2 * 2 * 2 * 2 * 16            # bytes of K and V per position
-    assert step.bytes == work.weight_bytes(s) + kv * 50 + 2 * 64 * 2
+    assert step.bytes == QWEN2.weight_bytes(s) + kv * 50 + 2 * 64 * 2
 
 
 def test_dense_head_counts_in_full():
     s = small_shapes()
     d = dataclasses.replace(s, packed=s.packed[:-1])
-    assert work.gemm(d, "lm_head", 3).flops == 2 * 3 * 64 * 512
-    assert work.weight_bytes(d) - work.weight_bytes(s) == pytest.approx(
+    assert QWEN2.gemm(d, "lm_head", 3).flops == 2 * 3 * 64 * 512
+    assert QWEN2.weight_bytes(d) - QWEN2.weight_bytes(s) == pytest.approx(
         2 * 64 * 512 - (2 * 32 * 512 + 4 * (512 // 32) * 32))
 
 
@@ -72,35 +78,46 @@ def test_unknown_device_raises():
         peaks.peaks_of("TPU v99")
 
 
-def test_config_files_match_the_program_config():
-    for c in spec.load_benchmark()["configs"]:
-        cfg = spec.load_config(c["name"])
-        mc = spec.model_config(cfg)
-        s = spec.Shapes.of(cfg)
-        assert (mc.num_layers, mc.d_model, mc.num_heads, mc.num_kv_heads,
-                mc.head_dim, mc.d_ff, mc.vocab_size, mc.norm_eps,
-                mc.tie_embeddings) == (
-            s.layers, s.d_model, s.heads, s.kv_heads, s.head_dim, s.d_ff,
-            s.vocab, s.eps, s.tied)
+# Fields of an architecture's ``Shapes`` and of the program's ``ModelConfig``
+# that state the same size; the first five every architecture has.
+SAME = {"layers": "num_layers", "vocab": "vocab_size", "heads": "num_heads",
+        "kv_heads": "num_kv_heads", "head_dim": "head_dim",
+        "d_model": "d_model", "d_ff": "d_ff", "eps": "norm_eps",
+        "tied": "tie_embeddings"}
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_config_files_match_the_program_config(name):
+    cfg = spec.load_config(name)
+    arch = spec.load_arch(cfg["arch"])
+    mc = arch.model_config(cfg)
+    s = arch.Shapes.of(cfg)
+    common = [k for k in SAME if hasattr(s, k)]
+    assert common[:5] == list(SAME)[:5]
+    assert [getattr(mc, SAME[k]) for k in common] == [
+        getattr(s, k) for k in common]
 
 
 @pytest.mark.parametrize("tied", [True, False])
-def test_weights_have_the_program_tree(tied):
-    """The seed's weights are the tree the program's model builds, with an
+@pytest.mark.parametrize("name", CONFIGS)
+def test_weights_have_the_program_tree(name, tied):
+    """The seed's weights, at the small cut of the configuration's
+    architecture, are the tree the program's model builds, with an
     ``lm_head`` leaf only where the head is not tied."""
     import functools
 
     import jax
 
     from bench import weights
-    from bench.tests import small
     from repro.models import build_model
 
-    cfg = dict(small.small_config("qwen2-1.5b"), tie_word_embeddings=tied)
-    s = spec.Shapes.of(cfg)
-    model = build_model(spec.model_config(cfg))
+    cfg = spec.load_config(name)
+    arch = spec.load_arch(cfg["arch"])
+    cfg = dict(arch.small_config(cfg), tie_word_embeddings=tied)
+    s = arch.Shapes.of(cfg)
+    model = build_model(arch.model_config(cfg))
     want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    got = jax.eval_shape(functools.partial(weights._make, s),
+    got = jax.eval_shape(functools.partial(arch._make, s),
                          weights.key_of(3))
     assert jax.tree.structure(got) == jax.tree.structure(want)
     assert [x.shape for x in jax.tree.leaves(got)] == [
